@@ -504,4 +504,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    # the CLI entry point owns the persistent compile cache
+    from repro.kernels.platform import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -95,7 +95,7 @@ class Observer:
         s["count"] += int(xnp.size)
         s["hist_x"] += np.bincount(self._quantize(xnp, cfg), minlength=256)
         if s["hist_w"] is None:
-            s["w_shape"] = tuple(int(d) for d in pre.w.shape[-2:])
+            s["w_shape"] = tuple(int(d) for d in pre.shape[-2:])
             if pre.q is not None:
                 qw = np.asarray(pre.q, np.int64).reshape(-1)
             else:

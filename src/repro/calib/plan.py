@@ -351,16 +351,6 @@ def _bank_key(path: str, plan: DesignPlan, designs) -> str:
     return f"{path}|{plan.mode}|{','.join(designs)}"
 
 
-def _plan_dlut_dtype():
-    """int16 on TPU (half the VMEM traffic of the Pallas gather),
-    pre-widened int32 elsewhere: the XLA twins gather from an int32
-    view, and widening a traced table at run time costs a 64Ki-element
-    convert per layer per decode step."""
-    import jax
-    import jax.numpy as jnp
-    return None if jax.default_backend() == "tpu" else jnp.int32
-
-
 def apply_plan(pparams, plan: DesignPlan, qcfg: QuantConfig, *,
                strict: bool = True):
     """Install a DesignPlan on a prequantized (optionally calibrated)
@@ -380,7 +370,8 @@ def apply_plan(pparams, plan: DesignPlan, qcfg: QuantConfig, *,
     model's sites (a plan built on another arch/size would otherwise
     silently serve plan.default everywhere)."""
     import jax.numpy as jnp
-    dlut_dtype = _plan_dlut_dtype()
+    from repro.kernels import platform
+    dlut_dtype = platform.delta_table_dtype()
     if plan.mode != qcfg.mode:
         raise ValueError(f"plan was built for mode {plan.mode!r} but the "
                          f"serving QuantConfig uses {qcfg.mode!r}")
@@ -388,7 +379,7 @@ def apply_plan(pparams, plan: DesignPlan, qcfg: QuantConfig, *,
     n_sites = [0]
 
     def install(node):
-        lead = tuple(int(d) for d in node.w.shape[:-2])
+        lead = tuple(int(d) for d in node.shape[:-2])
         n_sites[0] += int(np.prod(lead)) if lead else 1
         t = _site_tables(plan, node.path, lead, missing=missing)
         comp_col = None
@@ -431,7 +422,8 @@ def make_plan_injector(params, plan: DesignPlan, qcfg: QuantConfig, *,
     carries the per-layer index, like apply_plan.  strict=True rejects
     a plan that does not cover this model's sites."""
     import jax.numpy as jnp
-    dlut_dtype = _plan_dlut_dtype()
+    from repro.kernels import platform
+    dlut_dtype = platform.delta_table_dtype()
     if plan.mode != qcfg.mode:
         raise ValueError(f"plan was built for mode {plan.mode!r} but the "
                          f"training QuantConfig uses {qcfg.mode!r}")

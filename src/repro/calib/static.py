@@ -149,7 +149,7 @@ def apply_calibration(pparams, table: CalibrationTable, *,
                 f"calibration table was observed under mode "
                 f"{table.mode!r} but weights are prequantized for "
                 f"{node.mode!r} (site {node.path!r})")
-        lead = tuple(int(d) for d in node.w.shape[:-2])
+        lead = tuple(int(d) for d in node.shape[:-2])
         scales = np.zeros(lead, np.float32)
         zps = np.zeros(lead, np.float32)
         for idx in _lead_indices(lead):
@@ -211,7 +211,7 @@ def coverage(pparams, table: CalibrationTable) -> dict:
 
     def walk(node):
         if isinstance(node, qlin.QuantizedWeight):
-            lead = tuple(int(d) for d in node.w.shape[:-2])
+            lead = tuple(int(d) for d in node.shape[:-2])
             expected.extend(site_key(node.path, idx)
                             for idx in _lead_indices(lead))
         elif isinstance(node, dict):

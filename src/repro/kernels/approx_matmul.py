@@ -40,14 +40,14 @@ Four kernels:
 
 Block shapes default to MXU-aligned (128, 128) tiles; the M/N grid axes
 are marked ``parallel`` (K stays ``arbitrary`` — the output tile is
-revisited as accumulator).  ``interpret`` defaults to platform-adaptive
-(real lowering on TPU, interpret-mode emulation elsewhere; override with
-REPRO_PALLAS_INTERPRET=0/1 or an explicit ``interpret=`` argument).
+revisited as accumulator).  None of these four kernels builds for the
+TPU today (kernels.platform says why, and raises if one is requested
+there); on the CPU they run in interpret mode, where the tests check
+them against the blocked-XLA twins in kernels/ref.py.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -55,18 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Platform-adaptive interpret default: Pallas kernels lower for real
-    on TPU and fall back to interpret-mode emulation elsewhere (a
-    validation vehicle, not a fast path).  ``REPRO_PALLAS_INTERPRET=0/1``
-    overrides the platform; an explicit ``interpret=`` wins over both."""
-    if interpret is not None:
-        return interpret
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
-    return jax.default_backend() != "tpu"
+from . import platform
 
 
 def _sub_divisor(total: int, want: int) -> int:
@@ -132,11 +121,10 @@ def _delta_matmul_kernel(a_ref, b_ref, dlut_ref, out_ref, *, offset: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block", "interpret", "offset", "k_sub"))
+                   static_argnames=("block", "offset", "k_sub"))
 def delta_matmul(a: jax.Array, b: jax.Array, dlut: jax.Array,
                  block: Tuple[int, int, int] = (128, 128, 128),
-                 interpret: Optional[bool] = None, offset: int = 0,
-                 k_sub: int = 32) -> jax.Array:
+                 offset: int = 0, k_sub: int = 32) -> jax.Array:
     """S[m,n] = sum_k ( a[m,k]*b[k,n] + D[a[m,k]+off, b[k,n]+off] ).
 
     Bit-exact approximate matmul via the two-stage decomposition.
@@ -168,9 +156,9 @@ def delta_matmul(a: jax.Array, b: jax.Array, dlut: jax.Array,
         ],
         out_specs=pl.BlockSpec((TM, TN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_resolve_interpret(interpret),
+        compiler_params=platform.compiler_params(
+            "parallel", "parallel", "arbitrary"),
+        interpret=platform.pallas_interpret("delta_matmul"),
     )(a, b, dlut)
     if Kp > K:
         # padded k rows are (0,0) operand pairs: exact part adds 0, the
@@ -262,13 +250,12 @@ def _fused_qdot_kernel(idx_ref, scal_ref, x_ref, qw_ref, dlut_ref, ntab_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("asym", "compensate", "block",
-                                             "interpret", "offset", "k_sub"))
+                                             "offset", "k_sub"))
 def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array,
                scal: jax.Array, ntab: jax.Array, comp_r: jax.Array,
                dlut_idx: Optional[jax.Array] = None,
                block: Tuple[int, int, int] = (128, 128, 128),
-               interpret: Optional[bool] = None, offset: int = 0,
-               asym: bool = True, compensate: bool = False,
+               offset: int = 0, asym: bool = True, compensate: bool = False,
                k_sub: int = 32) -> jax.Array:
     """Fused quantized-linear: float x (M, K) -> float32 y (M, N).
 
@@ -341,9 +328,9 @@ def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array,
                           K=K),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_resolve_interpret(interpret),
+        compiler_params=platform.compiler_params(
+            "parallel", "parallel", "arbitrary"),
+        interpret=platform.pallas_interpret("fused_qdot"),
     )(idx, scal, xp, qwp, dlut, ntabp, comp_r.reshape(1, 256))
     return out[:M, :N]
 
@@ -371,10 +358,9 @@ def _lut_matmul_kernel(a_ref, b_ref, lut_ref, out_ref):
     out_ref[...] = jax.lax.fori_loop(0, a.shape[1], body, out_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block",))
 def lut_matmul(a: jax.Array, b: jax.Array, lut: jax.Array,
-               block: Tuple[int, int, int] = (128, 128, 128),
-               interpret: Optional[bool] = None) -> jax.Array:
+               block: Tuple[int, int, int] = (128, 128, 128)) -> jax.Array:
     """S[m,n] = sum_k LUT[a[m,k], b[k,n]]   (uint8-valued operands).
 
     a: (M,K), b: (K,N) integer arrays in [0,255]; lut: (256,256) int32.
@@ -398,9 +384,9 @@ def lut_matmul(a: jax.Array, b: jax.Array, lut: jax.Array,
         ],
         out_specs=pl.BlockSpec((TM, TN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_resolve_interpret(interpret),
+        compiler_params=platform.compiler_params(
+            "parallel", "parallel", "arbitrary"),
+        interpret=platform.pallas_interpret("lut_matmul"),
     )(a.astype(jnp.int32), b.astype(jnp.int32), lut.astype(jnp.int32))
 
 
@@ -436,10 +422,9 @@ def _residual_kernel(a_ref, b_ref, f_ref, g_ref, out_ref, *, offset: int = 0):
     out_ref[...] += exact + corr
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret", "offset"))
+@functools.partial(jax.jit, static_argnames=("block", "offset"))
 def residual_matmul(a: jax.Array, b: jax.Array, F: jax.Array, G: jax.Array,
                     block: Tuple[int, int, int] = (128, 128, 128),
-                    interpret: Optional[bool] = None,
                     offset: int = 0) -> jax.Array:
     """Exact matmul + rank-r approximate-error correction (float32 out).
 
@@ -465,8 +450,8 @@ def residual_matmul(a: jax.Array, b: jax.Array, F: jax.Array, G: jax.Array,
         ],
         out_specs=pl.BlockSpec((TM, TN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_resolve_interpret(interpret),
+        compiler_params=platform.compiler_params(
+            "parallel", "parallel", "arbitrary"),
+        interpret=platform.pallas_interpret("residual_matmul"),
     )(a.astype(jnp.int32), b.astype(jnp.int32),
       F.astype(jnp.float32), G.astype(jnp.float32))

@@ -1,116 +1,93 @@
-"""Pallas TPU kernel for the fused decode-step attention/cache op.
+"""Pallas TPU kernel for the attention half of the fused decode step.
 
-``decode_attention_step`` collapses the exact-fp ops that bound the
-serving decode step (the ~197 µs floor of ROADMAP's PR 4 analysis —
-attention, qk-norm, rope, KV-cache append) into ONE VMEM-resident body:
+``decode_attention_step`` is masked single-query GQA attention over a
+slot's KV cache, one grid step per (batch slot b, cache tile s):
 
-  * per grid slot (batch b, cache tile s): the query/new-key projections
-    are qk-rmsnormed and roped at the slot's cache position ``pos[b]``
+  * each slot attends up to its own cache position ``pos[b]``
     (scalar-prefetch operand — per-slot positions are what the batched
     MULTI-SLOT decode of the continuous-batching driver schedules);
-  * the fresh k/v row is emitted through the cache dtype and substituted
-    into its cache tile in-register, so attention reads the cache
-    exactly once and never waits on the append;
-  * masked single-query GQA attention runs tile-by-tile over the cache
-    with an online-softmax accumulator (flash-decode style: running max
-    / denominator / weighted-value scratch), so S_max never has to fit
-    VMEM whole — ``block_s`` tiles it (autotuned by perf_hillclimb).
+  * the cache is read tile by tile with an online-softmax accumulator
+    (flash-decode style: running max / denominator / weighted-value
+    scratch), so S_max never has to fit VMEM whole — ``block_s`` tiles
+    it;
+  * every contraction is a 2-D dot per KV head: the cache is viewed as
+    (B, S_max, n_kv·hd), so KV head g is the lane-aligned column block
+    [g·hd, (g+1)·hd) of a tile, and each query head keeps the logits and
+    values of its own KV head.  Mosaic lowers these dots; it refuses the
+    batched (head-major) ``dot_general`` an earlier version used.
 
-The KV append itself is a (B, 1, n_kv, hd) row write the caller applies
-around the kernel (kernels.ops.decode_attention): interpret mode cannot
-alias blocked outputs, and on hardware the row write is noise next to
-the attention read.  The kernel's twin is ``ref.decode_attention_ref``
-(bit-matched to the generic attention path); the Pallas lowering agrees
-with the twin to f32-softmax-reassociation ULPs (online vs two-pass
-softmax), asserted in tests/test_decode_attention.py.
-
-NB smoke configs have head_dim < 128 (sub-lane tiles) — fine under
-interpret mode; real-TPU runs want 128-lane head dims, like the other
-kernels in this package (ROADMAP real-TPU item).
+The rest of the decode step — qk-norm, rope at the cache position and
+the KV-cache append — runs as XLA ops shared with the twin
+(``ref.decode_rows`` / ``ref.append_rows``), so the appended cache rows
+are the twin's bit for bit, and the kernel reads the cache after the
+append.  ``kernels.ops.decode_attention`` composes the step; its twin
+``ref.decode_attention_ref`` is bit-matched to the generic attention
+path.  The kernel agrees with the twin to f32-softmax-reassociation
+ULPs (online vs two-pass softmax), asserted in
+tests/test_decode_attention.py.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .approx_matmul import _resolve_interpret, _sub_divisor
+from . import platform
 
 
-def _kernel_rope(x, pos, theta: float):
-    """Rope a (R, hd) block at scalar position ``pos`` (same formula as
-    models.layers.rope specialized to one position)."""
-    hd = x.shape[-1]
-    half = hd // 2
-    i = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
-    freqs = theta ** (-i / half)
-    ang = pos.astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[:, :half], x[:, half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+def _cache_tile(s_max: int, block_s: int, itemsize: int) -> int:
+    """Cache rows per grid step: the whole cache when it fits in
+    ``block_s`` rows (a block equal to the array dimension is always
+    legal), else ``block_s`` rounded down to the dtype's sublane tile (8
+    rows for 4-byte, 16 for 2-byte elements).  The grid then takes
+    ceil(s_max / ts) steps, and the kernel masks the rows of a ragged
+    last tile, so any cache length works without a cache-sized block."""
+    if s_max <= block_s:
+        return s_max
+    sub = max(32 // (8 * itemsize), 1) * 8
+    return max(block_s // sub, 1) * sub
 
 
-def _kernel_rmsnorm(x, gain, eps: float = 1e-6):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)) * gain
-
-
-def _expand_kv(t, group: int):
-    """(TS, Kv, hd) -> (H, TS, hd): repeat each kv head ``group`` times
-    (GQA expansion by broadcast, no data-dependent ops)."""
-    TS, Kv, hd = t.shape
-    t = t.transpose(1, 0, 2)                       # (Kv, TS, hd)
-    t = jnp.broadcast_to(t[:, None], (Kv, group, TS, hd))
-    return t.reshape(Kv * group, TS, hd)
-
-
-def _decode_attn_kernel(pos_ref, q_ref, kn_ref, vn_ref, gains_ref,
-                        kc_ref, vc_ref, o_ref, kr_ref, vr_ref,
-                        qs_ref, acc_ref, mx_ref, den_ref, *,
-                        group: int, theta: float, window: Optional[int],
-                        qk_norm: bool, ts: int, scale: float):
-    """Grid (B, S_max/TS); s innermost so the online-softmax scratch
-    accumulates across cache tiles of one slot."""
+def _decode_attn_kernel(pos_ref, q_ref, kc_ref, vc_ref, o_ref,
+                        acc_ref, mx_ref, den_ref, *, group: int,
+                        window: Optional[int], ts: int, s_max: int):
+    """Grid (B, ceil(S_max/TS)); s innermost so the online-softmax
+    scratch accumulates across the cache tiles of one slot.  Rows of a
+    ragged last tile past S_max hold no cache data: their logits are
+    masked (they lie past every position) and their values zeroed."""
     b = pl.program_id(0)
     s = pl.program_id(1)
     p = pos_ref[b]
-    H, hd = qs_ref.shape
+    H, hd = acc_ref.shape
 
     @pl.when(s == 0)
-    def _prep():
-        q = q_ref[...].reshape(H, hd).astype(jnp.float32)
-        kn = kn_ref[...].reshape(-1, hd).astype(jnp.float32)
-        if qk_norm:
-            q = _kernel_rmsnorm(q, gains_ref[0, :][None, :])
-            kn = _kernel_rmsnorm(kn, gains_ref[1, :][None, :])
-        if theta:
-            q = _kernel_rope(q, p, theta)
-            kn = _kernel_rope(kn, p, theta)
-        qs_ref[...] = q
-        kr_ref[...] = kn.reshape(kr_ref.shape).astype(kr_ref.dtype)
-        vr_ref[...] = vn_ref[...].astype(vr_ref.dtype)
+    def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         mx_ref[...] = jnp.full_like(mx_ref, -1e30)
         den_ref[...] = jnp.zeros_like(den_ref)
 
-    # cache tile with the fresh row substituted in-register (the row is
-    # read back through the cache dtype, matching the append-then-read
-    # semantics of the unfused path)
-    tpos = s * ts + jax.lax.broadcasted_iota(jnp.int32, (ts, 1, 1), 0)
-    kt = jnp.where(tpos == p, kr_ref[...].reshape(1, -1, hd),
-                   kc_ref[...].reshape(ts, -1, hd)).astype(jnp.float32)
-    vt = jnp.where(tpos == p, vr_ref[...].reshape(1, -1, hd),
-                   vc_ref[...].reshape(ts, -1, hd)).astype(jnp.float32)
+    q = q_ref[0]                                    # (H, hd) f32
+    kt = kc_ref[0]                                  # (TS, Kv·hd)
+    vt = vc_ref[0].astype(jnp.float32)
+    if s_max % ts:                                  # ragged last tile
+        vrow = s * ts + jax.lax.broadcasted_iota(jnp.int32, (ts, 1), 0)
+        vt = jnp.where(vrow < s_max, vt, 0.0)
+    head_kv = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // group
+    nt = (((1,), (1,)), ((), ()))                   # q @ k^T
+    hi = jax.lax.Precision.HIGHEST
 
-    kk = _expand_kv(kt, group)                     # (H, TS, hd)
-    vv = _expand_kv(vt, group)
-    lg = jax.lax.dot_general(
-        qs_ref[...], kk, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale          # (H, TS)
+    lg = jnp.zeros((H, ts), jnp.float32)
+    for g in range(H // group):
+        k_g = kt[:, g * hd:(g + 1) * hd].astype(jnp.float32)    # (TS, hd)
+        lg_g = jax.lax.dot_general(q, k_g, nt, precision=hi,
+                                   preferred_element_type=jnp.float32)
+        lg = jnp.where(head_kv == g, lg_g, lg)
+    lg = lg / math.sqrt(hd)
 
     trow = s * ts + jax.lax.broadcasted_iota(jnp.int32, (1, ts), 1)
     valid = trow <= p
@@ -122,77 +99,58 @@ def _decode_attn_kernel(pos_ref, q_ref, kn_ref, vn_ref, gains_ref,
     alpha = jnp.exp(mx_ref[...] - m_new)
     pe = jnp.exp(lg - m_new)                                 # (H, TS)
     den_ref[...] = den_ref[...] * alpha + pe.sum(axis=1, keepdims=True)
-    pv = jax.lax.dot_general(pe, vv, (((1,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)  # (H, hd)
+    pv = jnp.zeros((H, hd), jnp.float32)
+    for g in range(H // group):
+        v_g = vt[:, g * hd:(g + 1) * hd]                        # (TS, hd)
+        pv_g = jax.lax.dot(pe, v_g, precision=hi,
+                           preferred_element_type=jnp.float32)
+        pv = jnp.where(head_kv == g, pv_g, pv)
     acc_ref[...] = acc_ref[...] * alpha + pv
     mx_ref[...] = m_new
 
     @pl.when(s == pl.num_programs(1) - 1)
     def _epilogue():
-        o_ref[...] = (acc_ref[...] / den_ref[...]).reshape(o_ref.shape)
+        o_ref[0] = acc_ref[...] / den_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "theta", "window", "qk_norm", "group", "block_s", "interpret"))
-def decode_attention_step(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
-                          gains: jax.Array, k_cache: jax.Array,
+@functools.partial(jax.jit, static_argnames=("window", "group", "block_s"))
+def decode_attention_step(q: jax.Array, k_cache: jax.Array,
                           v_cache: jax.Array, pos: jax.Array, *,
-                          group: int, theta: float = 10000.0,
-                          window: Optional[int] = None, qk_norm: bool = False,
-                          block_s: int = 128,
-                          interpret: Optional[bool] = None
-                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One fused decode-attention step over a batch of cache slots.
+                          group: int, window: Optional[int] = None,
+                          block_s: int = 128) -> jax.Array:
+    """Single-query GQA attention of a batch of slots over their caches.
 
-    q: (B, H, hd) f32 pre-norm pre-rope; k_new/v_new: (B, Kv, hd);
-    gains: (2, hd) qk-norm gains (ignored unless ``qk_norm``);
-    k_cache/v_cache: (B, S_max, Kv, hd); pos: (B,) int32 per-slot cache
-    positions.  Returns (out (B, H, hd) f32, k_row, v_row) where
-    k_row/v_row are the roped new rows in the cache dtype — the caller
-    appends them at ``pos`` (kernels.ops.decode_attention does).
+    q: (B, H, hd) f32, already qk-normed and roped; k_cache/v_cache:
+    (B, S_max, Kv, hd) with this step's rows already appended; pos: (B,)
+    int32 per-slot positions of the query (cache rows <= pos are
+    attended, and > pos - window with a sliding window).  ``group`` =
+    H // Kv query heads per KV head.  Returns (B, H, hd) f32.
     """
     B, H, hd = q.shape
-    Kv = k_new.shape[1]
-    S_max = k_cache.shape[1]
-    ts = _sub_divisor(S_max, block_s)
-    grid = (B, S_max // ts)
+    S_max, Kv = k_cache.shape[1], k_cache.shape[2]
+    ts = _cache_tile(S_max, block_s, k_cache.dtype.itemsize)
+    kc = k_cache.reshape(B, S_max, Kv * hd)         # free: contiguous
+    vc = v_cache.reshape(B, S_max, Kv * hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                      # pos
-        grid=grid,
+        grid=(B, pl.cdiv(S_max, ts)),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, s, pr: (b, 0, 0)),      # q
-            pl.BlockSpec((1, Kv, hd), lambda b, s, pr: (b, 0, 0)),     # k_new
-            pl.BlockSpec((1, Kv, hd), lambda b, s, pr: (b, 0, 0)),     # v_new
-            pl.BlockSpec((2, hd), lambda b, s, pr: (0, 0)),            # gains
-            pl.BlockSpec((1, ts, Kv, hd), lambda b, s, pr: (b, s, 0, 0)),
-            pl.BlockSpec((1, ts, Kv, hd), lambda b, s, pr: (b, s, 0, 0)),
+            pl.BlockSpec((1, H, hd), lambda b, s, pr: (b, 0, 0)),
+            pl.BlockSpec((1, ts, Kv * hd), lambda b, s, pr: (b, s, 0)),
+            pl.BlockSpec((1, ts, Kv * hd), lambda b, s, pr: (b, s, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, s, pr: (b, 0, 0)),      # out
-            pl.BlockSpec((1, Kv, hd), lambda b, s, pr: (b, 0, 0)),     # k row
-            pl.BlockSpec((1, Kv, hd), lambda b, s, pr: (b, 0, 0)),     # v row
-        ],
+        out_specs=pl.BlockSpec((1, H, hd), lambda b, s, pr: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H, hd), jnp.float32),       # roped/normed query
             pltpu.VMEM((H, hd), jnp.float32),       # online-softmax acc
             pltpu.VMEM((H, 1), jnp.float32),        # running max
             pltpu.VMEM((H, 1), jnp.float32),        # running denominator
         ],
     )
-    out, kr, vr = pl.pallas_call(
-        functools.partial(_decode_attn_kernel, group=group, theta=theta,
-                          window=window, qk_norm=qk_norm, ts=ts,
-                          scale=1.0 / (hd ** 0.5)),
+    return pl.pallas_call(
+        functools.partial(_decode_attn_kernel, group=group, window=window,
+                          ts=ts, s_max=S_max),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, Kv, hd), k_cache.dtype),
-            jax.ShapeDtypeStruct((B, Kv, hd), v_cache.dtype),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=_resolve_interpret(interpret),
-    )(pos.astype(jnp.int32), q.astype(jnp.float32),
-      k_new.astype(jnp.float32), v_new.astype(jnp.float32),
-      gains.astype(jnp.float32), k_cache, v_cache)
-    return out, kr, vr
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
+        compiler_params=platform.compiler_params("parallel", "arbitrary"),
+        interpret=platform.pallas_interpret("decode_attention_step"),
+    )(pos.astype(jnp.int32), q.astype(jnp.float32), kc, vc)

@@ -3,30 +3,32 @@
 ``approx_matmul`` is the operator the quantized layers call.  Backends:
 
   'delta'    — the two-stage fast path (bit-exact, recommended): exact
-               int32 product on the MXU + int16 delta-table gather.
-               Platform-adaptive lowering: the Pallas kernel on TPU,
-               its blocked-XLA twin elsewhere (interpret-mode Pallas is
-               a validation vehicle, not a fast path).  Pads any shape;
-               the signed offset folds into the gather index (no
-               operand pre-shift).
+               int32 product on the MXU + delta-table gather.  Lowered
+               as kernels.platform chooses: the blocked-XLA twin on
+               every platform today (the Pallas kernel's gather does
+               not build for the TPU).  Any shape; the signed offset
+               folds into the gather index (no operand pre-shift).
   'fused'    — the fused quantize->delta->dequant serving kernel
                (``fused_qdot`` below).  quant.linear dispatches to it
                when a QuantizedWeight carries calibrated static
                activation scales; integer-operand approx_matmul calls
                with backend='fused' fall back to 'delta' (same integer
                core, nothing to fuse without the float ends).
-  'pallas'   — the delta Pallas kernel explicitly (interpret mode off
-               TPU; what the kernel tests exercise).
+  'pallas'   — the delta Pallas kernel explicitly (interpret mode on
+               the CPU, what the kernel tests exercise; refused on the
+               TPU).
   'delta_xla'— the blocked-XLA twin explicitly (exact dot + K-blocked
                delta gather); what big-model graphs lower with in place
                of the old (M,K,N)-index-surface product-LUT gather.
   'pallas_legacy'
              — the original per-k LUT-gather Pallas kernel, kept for
-               A/B benchmarking (benchmarks/run.py kernel_microbench).
+               A/B benchmarking (benchmarks/run.py kernel_microbench;
+               refused on the TPU).
   'xla'      — jnp.take product-LUT formulation (ref semantics); the
                dry-run path, lowers everywhere.
-  'residual' — exact MXU matmul + rank-r correction (fast, approximate
-               emulation; r configurable; NOT bit-exact).
+  'residual' — exact MXU matmul + rank-r correction (approximate
+               emulation; r configurable; NOT bit-exact; refused on the
+               TPU).
   'exact'    — plain integer matmul (the baseline multiplier).
 
 All backends share a straight-through-estimator VJP: the backward pass
@@ -42,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ref
+from . import platform, ref
 from .approx_matmul import delta_matmul, lut_matmul, residual_matmul
 from .approx_matmul import fused_qdot as _fused_qdot_pallas
 
@@ -122,19 +124,17 @@ def _approx_matmul_fwd_impl(a, b, design, backend, rank, signed=False):
         out = ref.exact_matmul_ref(a2, b)
     elif backend == "xla":
         # Faithful gather formulation. NB: materializes the (M,K,N) index
-        # surface unless XLA fuses it — fine at test/benchmark scale, use
-        # 'residual_xla' for the big-model graphs (see DESIGN.md §Perf).
+        # surface unless XLA fuses it — fine at test/benchmark scale; the
+        # big-model graphs use 'delta' (same bits, K-blocked gather).
         out = ref.approx_matmul_ref(a2, b, lut(), offset=off)
     elif backend in ("pallas", "delta", "delta_xla", "fused"):
-        # Two-stage delta path: exact MXU product + int16 delta gather.
+        # Two-stage delta path: exact MXU product + delta gather.
         # Signed operands index the table via the folded-in offset; no
         # pre-shift pass, and shapes need not be block multiples.
         # 'delta' (and 'fused', which on integer operands has no float
-        # ends to fuse) picks the lowering for the platform: the Pallas
-        # kernel on real TPU — interpret resolves platform-adaptively
-        # inside delta_matmul — the blocked-XLA twin on CPU/GPU.
-        on_tpu = jax.default_backend() == "tpu"
-        if backend == "pallas" or (backend in ("delta", "fused") and on_tpu):
+        # ends to fuse) takes the platform's qdot lowering.
+        if backend == "pallas" or (backend in ("delta", "fused")
+                                   and platform.lowering("qdot") == "pallas"):
             out = delta_matmul(a2, b,
                                jnp.asarray(get_delta_lut(design, signed)),
                                offset=off)
@@ -205,51 +205,37 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      lowering: str = "auto"):
     """The fused decode-step attention/cache op: qk-norm + rope at the
     slot's cache position + KV-cache append + masked single-query GQA
-    attention, one lowered body (the step-level twin of ``fused_qdot``).
+    attention (the step-level twin of ``fused_qdot``).
 
     q: (B, 1, n_heads, hd) pre-norm pre-rope; k/v: (B, 1, n_kv, hd).
     idx: scalar int32 (uniform decode) or (B,) int32 per-slot cache
     positions (batched multi-slot decode — the continuous-batching
-    driver's schedule).  ``lowering``: 'auto' (Pallas kernel on TPU, the
-    bit-matched blocked-XLA twin elsewhere), 'pallas', or 'xla'.
+    loop's schedule).  ``lowering``: 'auto' (kernels.platform: the
+    Pallas kernel on the TPU, the bit-matched XLA twin elsewhere),
+    'pallas', or 'xla'.  Both lowerings share the norm/rope/append ops,
+    so the returned caches are identical; the attention output agrees
+    to f32 reassociation ULPs.
 
     Returns (out (B, 1, n_heads*hd) f32, k_cache', v_cache').
     """
     idx = jnp.asarray(idx)
-    on_tpu = jax.default_backend() == "tpu"
-    if lowering == "pallas" or (lowering == "auto" and on_tpu):
-        from .attention import decode_attention_step
-        B = q.shape[0]
-        qk_norm = q_gain is not None
-        gains = (jnp.stack([jnp.asarray(q_gain), jnp.asarray(k_gain)])
-                 if qk_norm else jnp.ones((2, head_dim), jnp.float32))
-        pos = jnp.broadcast_to(idx.reshape(-1), (B,))
-        out, krow, vrow = decode_attention_step(
-            q.reshape(B, n_heads, head_dim),
-            k.reshape(B, n_kv, head_dim), v.reshape(B, n_kv, head_dim),
-            gains, k_cache, v_cache, pos, group=n_heads // max(n_kv, 1),
-            theta=rope_theta, window=window, qk_norm=qk_norm,
-            block_s=block_s)
-        # the kernel emits the roped cache-dtype rows; append them here
-        # (a (B, 1, Kv, hd) write — in place when the caller donates
-        # the cache buffers, as the TPU serve step does)
-        if idx.ndim == 1:
-            upd = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(
-                c, n[None], (i, 0, 0)))
-            ck = upd(k_cache, krow, idx)
-            cv = upd(v_cache, vrow, idx)
-        else:
-            ck = jax.lax.dynamic_update_slice(k_cache, krow[:, None],
-                                              (0, idx, 0, 0))
-            cv = jax.lax.dynamic_update_slice(v_cache, vrow[:, None],
-                                              (0, idx, 0, 0))
-        return out.reshape(B, 1, n_heads * head_dim), ck, cv
-    if lowering not in ("auto", "xla"):
-        raise ValueError(lowering)
-    return ref.decode_attention_ref(
-        q, k, v, k_cache, v_cache, idx, n_heads=n_heads, n_kv=n_kv,
-        head_dim=head_dim, rope_theta=rope_theta, window=window,
-        q_gain=q_gain, k_gain=k_gain)
+    if platform.lowering("decode_attention", lowering) == "xla":
+        return ref.decode_attention_ref(
+            q, k, v, k_cache, v_cache, idx, n_heads=n_heads, n_kv=n_kv,
+            head_dim=head_dim, rope_theta=rope_theta, window=window,
+            q_gain=q_gain, k_gain=k_gain)
+    from .attention import decode_attention_step
+    B = q.shape[0]
+    q, k = ref.decode_rows(q, k, idx, rope_theta=rope_theta,
+                           q_gain=q_gain, k_gain=k_gain)
+    # the row append is a (B, 1, Kv, hd) write — in place when the
+    # caller donates the cache buffers, as the TPU serve step does
+    ck, cv = ref.append_rows(k_cache, v_cache, k, v, idx)
+    pos = jnp.broadcast_to(idx.reshape(-1), (B,))
+    out = decode_attention_step(
+        q.reshape(B, n_heads, head_dim), ck, cv, pos,
+        group=n_heads // max(n_kv, 1), window=window, block_s=block_s)
+    return out.reshape(B, 1, n_heads * head_dim), ck, cv
 
 
 # ---------------------------------------------------------------------------
@@ -287,8 +273,9 @@ def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array, *,
     (1, N)/(N,) (per-channel).  colsum: colsum(qw) for the asym_u8
     zero-point cross term.  comp_*: mean-field compensation tables
     (row table (256,), precomputed column colsum (N,), scalar mean)
-    when ``compensate``.  ``lowering``: 'auto' (Pallas kernel on TPU,
-    blocked-XLA twin elsewhere), 'pallas', or 'xla'.
+    when ``compensate``.  ``lowering``: 'auto' (kernels.platform: the
+    blocked-XLA twin on every platform today), 'pallas' (the Pallas
+    kernel — interpret mode on the CPU, refused on the TPU), or 'xla'.
     """
     lead = x.shape[:-1]
     K = x.shape[-1]
@@ -308,16 +295,13 @@ def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array, *,
           else jnp.zeros((256,), jnp.float32))
     layer = (jnp.asarray(dlut_idx, jnp.int32).reshape(())
              if dlut_idx is not None else None)
-    on_tpu = jax.default_backend() == "tpu"
-    if lowering == "pallas" or (lowering == "auto" and on_tpu):
+    if platform.lowering("qdot", lowering) == "pallas":
         out = _fused_qdot_pallas(x2, qw, jnp.asarray(dlut), scal, ntab, cr,
                                  dlut_idx=layer, block=tuple(block),
                                  offset=off, asym=not signed,
                                  compensate=compensate, k_sub=k_sub)
-    elif lowering in ("auto", "xla"):
+    else:
         out = ref.fused_qdot_ref(x2, qw, dlut, scal, ntab, cr, offset=off,
                                  asym=not signed, compensate=compensate,
                                  k_block=k_block, layer=layer)
-    else:
-        raise ValueError(lowering)
     return out.reshape(*lead, N)

@@ -54,7 +54,8 @@ def delta_matmul_ref(a, b, dlut: np.ndarray, offset: int = 0,
 
     S[m,n] = sum_k ( a[m,k]*b[k,n] + D[a[m,k]+off, b[k,n]+off] ) — the
     XLA twin of kernels.approx_matmul.delta_matmul and what the 'delta'
-    backend lowers with off-TPU: the bulk of the arithmetic is a plain
+    backend lowers with on every platform (kernels.platform says why
+    the TPU runs it too): the bulk of the arithmetic is a plain
     dot (MXU/BLAS-friendly) and the gathered payload is the half-width
     int16 delta table (core.lut.build_delta_lut).  Unlike the old
     approx_matmul_ref it never materializes the whole (M,K,N) index
@@ -104,8 +105,9 @@ def fused_qdot_ref(x, qw, dlut, scal, ntab, comp_r, offset: int = 0,
                    asym: bool = True, compensate: bool = False,
                    k_block: int = 32, layer=None):
     """Blocked-XLA twin of kernels.approx_matmul.fused_qdot — the fused
-    quantize -> (exact dot + delta gather) -> dequant serving path for
-    non-TPU platforms (float x in, float32 out, same operand layout).
+    quantize -> (exact dot + delta gather) -> dequant serving path, and
+    the lowering every platform serves with (kernels.platform says why
+    the TPU runs it too; float x in, float32 out, same operand layout).
 
     x: (M, K) float; qw: (K, N) int32 prequantized weights;
     dlut: (256, 256) delta table, or a stacked (L, 256, 256) bank with
@@ -188,12 +190,47 @@ def _rope(x, positions, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
+def decode_rows(q, k, idx, *, rope_theta: float = 10000.0, q_gain=None,
+                k_gain=None):
+    """The decode step's per-row prologue: (optional) qk rmsnorm, then
+    rope at the slot's cache position.  q: (B, S, n_heads, hd); k:
+    (B, S, n_kv, hd); idx: scalar int32 or (B,) per-slot positions.
+    Shared by the twin and the Pallas path of kernels.ops, so both
+    append the same cache rows bit for bit."""
+    S = q.shape[1]
+    positions = (idx[:, None] + jnp.arange(S)) if idx.ndim == 1 \
+        else (idx + jnp.arange(S))
+    if q_gain is not None:
+        q = _rmsnorm(q, q_gain)
+        k = _rmsnorm(k, k_gain)
+    if rope_theta:
+        q = _rope(q, positions, rope_theta)
+        k = _rope(k, positions, rope_theta)
+    return q, k
+
+
+def append_rows(k_cache, v_cache, k, v, idx):
+    """Write the new rows into the caches at ``idx`` (scalar, or (B,)
+    per slot), cast to the cache dtype exactly like the cache update of
+    the generic attention path."""
+    if idx.ndim == 1:
+        upd = jax.vmap(
+            lambda c, n, i: jax.lax.dynamic_update_slice(c, n, (i, 0, 0)))
+        return (upd(k_cache, k.astype(k_cache.dtype), idx),
+                upd(v_cache, v.astype(v_cache.dtype), idx))
+    return (jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype),
+                                         (0, idx, 0, 0)),
+            jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
+                                         (0, idx, 0, 0)))
+
+
 def decode_attention_ref(q, k, v, k_cache, v_cache, idx, *, n_heads: int,
                          n_kv: int, head_dim: int,
                          rope_theta: float = 10000.0, window=None,
                          q_gain=None, k_gain=None):
-    """XLA twin of kernels.attention.decode_attention_step — the fused
-    decode-step attention/cache op for non-TPU platforms.
+    """XLA twin of the fused decode-step attention/cache op
+    (kernels.ops.decode_attention), the lowering 'auto' picks off the
+    TPU.
 
     One logical op covers what the decode step previously spread over
     models.layers.attention: (optional) qk rmsnorm, rope at the slot's
@@ -220,22 +257,9 @@ def decode_attention_ref(q, k, v, k_cache, v_cache, idx, *, n_heads: int,
     per_slot = idx.ndim == 1
     positions = (idx[:, None] + jnp.arange(S)) if per_slot \
         else (idx + jnp.arange(S))
-    if q_gain is not None:
-        q = _rmsnorm(q, q_gain)
-        k = _rmsnorm(k, k_gain)
-    if rope_theta:
-        q = _rope(q, positions, rope_theta)
-        k = _rope(k, positions, rope_theta)
-    if per_slot:
-        upd = jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice(c, n, (i, 0, 0)))
-        ck = upd(k_cache, k.astype(k_cache.dtype), idx)
-        cv = upd(v_cache, v.astype(v_cache.dtype), idx)
-    else:
-        ck = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype),
-                                          (0, idx, 0, 0))
-        cv = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
-                                          (0, idx, 0, 0))
+    q, k = decode_rows(q, k, idx, rope_theta=rope_theta, q_gain=q_gain,
+                       k_gain=k_gain)
+    ck, cv = append_rows(k_cache, v_cache, k, v, idx)
     S_k = ck.shape[1]
     group = n_heads // max(n_kv, 1)
     qg = q.reshape(B, S, n_kv, group, head_dim)

@@ -11,7 +11,8 @@ block, cache written in one slice, and a decode handoff bit-identical
 to stepping the prompt token by token (--prefill loop keeps the old
 per-token path for A/B).  The decode step's attention/rope/cache-append
 runs through the fused decode-attention op (kernels.ops.decode_attention
-— Pallas on TPU, bit-matched XLA twin elsewhere).
+— its attention is a Pallas kernel on the TPU, the bit-matched XLA twin
+elsewhere; kernels.platform decides each op's lowering).
 
 Quantization precomputation ladder (see quant/linear.py):
   --prequantize      cache weight quantization once (q/scale/zp/colsum)
@@ -29,9 +30,10 @@ Quantization precomputation ladder (see quant/linear.py):
 --calibrate and --plan imply --prequantize (the caches they attach to).
 
 With static scales installed (--calibrate / --plan) the backend
-defaults to 'fused': one kernel quantizes the activations, runs the
-two-stage exact-dot + delta-gather (the plan's per-layer tables ride
-the scan as kernel operands) and dequantizes in the epilogue, and the
+defaults to 'fused' (else 'delta'): one lowered body quantizes the
+activations, runs the two-stage exact-dot + delta-gather (the plan's
+per-layer tables ride the scan as operands) and dequantizes in the
+epilogue, and the
 attention wq|wk|wv / mlp gate|up projections are MERGED into single
 calls (quant.fuse_projections — bit-identical per column; disable with
 --no-fuse-proj to A/B).  Pass an explicit --backend to A/B the unfused
@@ -58,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.kernels import platform
 from repro.models import transformer as T
 from repro.quant import QuantConfig
 from repro.train import make_prefill_step, make_serve_step
@@ -73,6 +76,12 @@ def prepare_params(params, cfg, qcfg, args):
     """Apply the requested precomputation ladder to a params tree.
     Returns (params, notes) — notes says what was installed.
 
+    When any rung is requested, ``params`` is CONSUMED: each float
+    weight is freed as soon as its quantized form exists, and merged
+    projections free their members, so the prepare sequence never holds
+    two copies of the layer weights (at qwen3-1.7b widths the float
+    masters alone are 5.6 GB).  The serving tree keeps no master.
+
     Calibration draws from its OWN rng so enabling --calibrate never
     shifts the serving-prompt stream (A/B runs with and without it see
     identical requests)."""
@@ -81,7 +90,7 @@ def prepare_params(params, cfg, qcfg, args):
     wrap = args.prequantize or args.calibrate or args.plan
     if not wrap:
         return params, notes
-    params = prequantize_weights(params, qcfg)
+    params = prequantize_weights(params, qcfg, consume=True)
     notes.append("prequantized weights"
                  + (" (per-channel)" if qcfg.w_per_channel else ""))
     if args.calibrate:
@@ -115,19 +124,10 @@ def prepare_params(params, cfg, qcfg, args):
         params = attach_comp_cols(params, qcfg)
         notes.append("fused backend (cached compensation colsums)")
     if not args.no_fuse_proj:
-        params = fuse_projections(params)
+        params = fuse_projections(params, consume=True)
         notes.append("merged wq|wk|wv -> wqkv, w_gate|w_up -> w_gateup "
                      "(fuse_projections)")
     return params, notes
-
-
-def _donate():
-    """Donate the decode state into the jitted steps on TPU (the KV
-    caches update in place — at real model scale the state is the
-    memory budget).  On CPU donation is measured SLOWER for chained
-    decode (buffer reallocation per step) and the smoke-scale state is
-    tiny, so keep the buffers."""
-    return (1,) if jax.default_backend() == "tpu" else ()
 
 
 def _scatter_slot(state, one, slot: int):
@@ -216,7 +216,9 @@ def serve_continuous(params, cfg, qcfg, args, rng):
     return out, np.asarray(logits)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The serving CLI's options (``main`` and chip_smoke.py read them
+    the same way)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--smoke", action="store_true")
@@ -227,7 +229,7 @@ def main(argv=None):
     ap.add_argument("--backend", default=None,
                     help="approximate-matmul backend (quant.QuantConfig)."
                          "  Default: 'fused' when static act scales are "
-                         "installed (--calibrate/--plan), else 'xla'")
+                         "installed (--calibrate/--plan), else 'delta'")
     ap.add_argument("--quant-mode", default="asym_u8",
                     choices=["asym_u8", "sym_i8"],
                     help="asym_u8: unsigned multiplier + zero-point "
@@ -261,15 +263,25 @@ def main(argv=None):
                          "through --requests slots with per-slot cache "
                          "positions (finished slots re-prefill from the "
                          "queue)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
+
+def quant_config(args) -> QuantConfig:
+    """The serving QuantConfig the options select: the 'fused' backend
+    once static activation scales exist (--calibrate/--plan), else
+    'delta' (the same bits as the 'xla' product-LUT oracle, without its
+    (M, K, N) index surface)."""
     backend = args.backend or (
-        "fused" if (args.calibrate or args.plan) else "xla")
-    qcfg = QuantConfig(design=args.design, backend=backend,
+        "fused" if (args.calibrate or args.plan) else "delta")
+    return QuantConfig(design=args.design, backend=backend,
                        mode=args.quant_mode,
-                       w_per_channel=args.per_channel,
-                       inference=True)
+                       w_per_channel=args.per_channel, inference=True)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
+    qcfg = quant_config(args)
     B = args.requests
     s_max = args.prompt_len + args.gen_len
 
@@ -290,9 +302,10 @@ def main(argv=None):
         enc_out = T._run_encoder(params, fr, cfg, qcfg)
 
     state = T.init_decode_state(cfg, B, s_max, enc_out=enc_out)
-    serve_c = jax.jit(make_serve_step(cfg, qcfg), donate_argnums=_donate())
+    serve_c = jax.jit(make_serve_step(cfg, qcfg),
+                      donate_argnums=platform.donate(1))
     prefill_c = jax.jit(make_prefill_step(cfg, qcfg),
-                        donate_argnums=_donate())
+                        donate_argnums=platform.donate(1))
     prompts_dev = jnp.asarray(prompts)
     tok0 = jnp.zeros((B, 1), jnp.int32)
 
@@ -345,4 +358,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the CLI entry point owns the persistent compile cache; tests call
+    # main() directly and leave the process configuration alone
+    platform.enable_compile_cache()
     main()

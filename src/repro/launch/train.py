@@ -126,4 +126,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the CLI entry point owns the persistent compile cache
+    from repro.kernels import platform
+    platform.enable_compile_cache()
     main()

@@ -32,7 +32,8 @@ The cached (q, scale, zp) are value-identical to what on-the-fly
 quantization computes (per scan slice), so outputs agree to
 float-reduction ULPs — the two graph shapes may fuse float sums
 differently — and greedy decode tokens match.  The master weights ride
-along for the STE/exact branches.
+along for the STE/exact branches of training and QAT; the serving form
+(prequantized under an inference QuantConfig) drops them.
 
 Calibration observers: ``repro.calib.observe`` installs a process-global
 observer via ``set_observer``; qdot reports (x, site, cfg) for every
@@ -114,6 +115,11 @@ class QuantizedWeight:
     axes are preserved on every field so jax.lax.scan slices them all in
     lockstep with per-slice values identical to on-the-fly computation.
 
+    The SERVING form has ``w`` None: an inference-mode qdot reads only
+    the cached quantization, so prequantize_weights under an inference
+    QuantConfig keeps no float master (at qwen3-1.7b widths the masters
+    are 5.6 GB the chip would otherwise hold twice).
+
     Fields (None = not precomputed; qdot falls back to dynamic work):
       q, scale, zp  cached weight quantization (zp None for sym_i8);
                     per-channel scales have shape (…, 1, N)
@@ -182,11 +188,11 @@ class QuantizedWeight:
 
     @property
     def ndim(self):
-        return self.w.ndim
+        return len(self.shape)
 
     @property
     def shape(self):
-        return self.w.shape
+        return (self.w if self.w is not None else self.q).shape
 
     def replace(self, **kw) -> "QuantizedWeight":
         d = dict(w=self.w, q=self.q, scale=self.scale, zp=self.zp,
@@ -215,7 +221,7 @@ class QuantizedWeight:
     def __repr__(self):
         extras = [k for k in ("act_scale", "dlut")
                   if getattr(self, k) is not None]
-        return (f"QuantizedWeight(shape={tuple(self.w.shape)}, "
+        return (f"QuantizedWeight(shape={tuple(self.shape)}, "
                 f"mode={self.mode!r}, path={self.path!r}, "
                 f"per_channel={self.per_channel}"
                 + (f", +{'/'.join(extras)}" if extras else "") + ")")
@@ -234,7 +240,8 @@ def _quantize_weight(w: jax.Array, cfg: QuantConfig,
                      path: str = "") -> QuantizedWeight:
     """Quantize over the trailing (K, N) axes; leading axes are stacked
     layers/experts and keep their own scales (matching what on-the-fly
-    qdot computes per scan slice)."""
+    qdot computes per scan slice).  The master rides along unless
+    ``cfg.inference`` (the serving form)."""
     axis = _weight_axis(w, cfg.w_per_channel)
     if cfg.signed:
         q, s = quantize_int8(w, axis)
@@ -242,8 +249,9 @@ def _quantize_weight(w: jax.Array, cfg: QuantConfig,
     else:
         q, s, zp = quantize_uint8(w, axis)
         colsum = q.sum(axis=-2, keepdims=True).astype(jnp.float32)
-    return QuantizedWeight(w, q, s, zp, colsum=colsum, mode=cfg.mode,
-                           path=path, per_channel=cfg.w_per_channel)
+    return QuantizedWeight(None if cfg.inference else w, q, s, zp,
+                           colsum=colsum, mode=cfg.mode, path=path,
+                           per_channel=cfg.w_per_channel)
 
 
 def is_dense_weight(k, v) -> bool:
@@ -280,18 +288,31 @@ def walk_dense(node, fn, path=""):
     return node
 
 
-def prequantize_weights(params, cfg: QuantConfig):
+def prequantize_weights(params, cfg: QuantConfig, *, consume: bool = False):
     """Return a copy of ``params`` with every qdot-bound dense weight
     wrapped in a QuantizedWeight (call once, outside jit).
 
     Each wrapper records its tree path (the calibration site name used
     by repro.calib).  No-op when cfg.enabled is False.  Used by
     launch/serve.py (--prequantize) to drop per-decode-step weight
-    quantization.
+    quantization.  Under an inference ``cfg`` the wrappers hold no
+    float master (the serving form).
+
+    ``consume=True`` frees each float weight as soon as its quantized
+    form exists, so the float and quantized layer weights are never
+    resident together (one leaf at a time is); ``params`` must not be
+    used afterwards.  serve.prepare_params consumes the tree it is
+    given.
     """
     if not cfg.enabled:
         return params
-    return walk_dense(params, lambda v, p: _quantize_weight(v, cfg, p))
+
+    def wrap(v, path):
+        qw = _quantize_weight(v, cfg, path)
+        if consume and qw.w is None:
+            v.delete()
+        return qw
+    return walk_dense(params, wrap)
 
 
 def _warn_stale(pre: QuantizedWeight, cfg: QuantConfig) -> None:
@@ -428,10 +449,25 @@ def qdot(x: jax.Array, w: jax.Array, cfg: QuantConfig) -> jax.Array:
         if pre.mode != cfg.mode or (
                 pre.q is not None and not pre.merged
                 and pre.per_channel != cfg.w_per_channel):
+            if w is None:
+                raise ValueError(
+                    f"QuantizedWeight cache built for mode={pre.mode!r}/"
+                    f"per_channel={pre.per_channel} used with "
+                    f"QuantConfig(mode={cfg.mode!r}, w_per_channel="
+                    f"{cfg.w_per_channel}) (site {pre.path!r}): this "
+                    f"serving-form wrapper has no master weights to "
+                    f"requantize from.  Prepare the tree with the "
+                    f"serving QuantConfig.")
             _warn_stale(pre, cfg)   # loud: requantizing every step
             pre = None
     if _OBSERVER is not None and pre is not None:
         _OBSERVER.record(x, pre, cfg)
+    if w is None and not (cfg.enabled and cfg.inference):
+        raise ValueError(
+            f"serving-form QuantizedWeight (site {pre.path!r}) has no "
+            f"master weights: it serves only an inference QuantConfig "
+            f"with an approximate design (got design={cfg.design!r}, "
+            f"inference={cfg.inference})")
     if not cfg.enabled:
         return jnp.matmul(x, w)
     if cfg.signed:
@@ -562,10 +598,10 @@ def _merge_group(parts, name: str):
     if not all(isinstance(p, QuantizedWeight) and p.q is not None
                for p in parts):
         return None
-    lead = tuple(int(d) for d in parts[0].w.shape[:-2])
-    K = parts[0].w.shape[-2]
-    if any(p.mode != parts[0].mode or tuple(p.w.shape[:-2]) != lead
-           or p.w.shape[-2] != K for p in parts):
+    lead = tuple(int(d) for d in parts[0].shape[:-2])
+    K = parts[0].shape[-2]
+    if any(p.mode != parts[0].mode or tuple(p.shape[:-2]) != lead
+           or p.shape[-2] != K for p in parts):
         return None
     # the members consume the SAME activations, so calibrated static
     # quantizers must agree — they do by construction (same observer
@@ -591,14 +627,14 @@ def _merge_group(parts, name: str):
             if not all(np.array_equal(b[i[li]], t0)
                        for b, i in zip(banks[1:], idxs[1:])):
                 return None
-    ns = [int(p.w.shape[-1]) for p in parts]
+    ns = [int(p.shape[-1]) for p in parts]
     comp_cols = [p.comp_col for p in parts]
     merged_comp_col = (jnp.concatenate(comp_cols, axis=-1)
                        if all(c is not None for c in comp_cols) else None)
     prefix = parts[0].path.rsplit(".", 1)[0] if "." in parts[0].path else ""
     base = parts[0]
     return QuantizedWeight(
-        w=jnp.concatenate([p.w for p in parts], axis=-1),
+        w=None,
         q=jnp.concatenate([p.q for p in parts], axis=-1),
         scale=jnp.concatenate(
             [_bcast_col(p.scale, lead, n) for p, n in zip(parts, ns)],
@@ -616,7 +652,7 @@ def _merge_group(parts, name: str):
         per_channel=True, merged=True)
 
 
-def fuse_projections(params):
+def fuse_projections(params, *, consume: bool = False):
     """Serving-time projection merging over the decoder units: attention
     wq|wk|wv -> wqkv and (GLU) mlp w_gate|w_up -> w_gateup, concatenated
     along the output axis.  At decode scale (M = B tokens) every qdot
@@ -629,26 +665,35 @@ def fuse_projections(params):
     scan consumes separate operands) — are left untouched.  Apply AFTER
     the rest of the precomputation ladder (prequantize -> calibrate ->
     plan -> comp cols); launch/serve.py does this by default
-    (--no-fuse-proj to A/B)."""
+    (--no-fuse-proj to A/B).  The merged wrappers are serving-form
+    (no float master).
+
+    ``consume=True`` frees each group's member weights once the merged
+    copy exists, so only one group is ever resident twice; ``params``
+    must not be used afterwards (serve.prepare_params)."""
+    def merge(node, names, merged_name):
+        parts = [node[k] for k in names]
+        m = _merge_group(parts, merged_name)
+        if m is None:
+            return node
+        if consume:
+            for p in parts:
+                for a in (p.w, p.q):
+                    if a is not None:
+                        a.delete()
+        node = {k: v for k, v in node.items() if k not in names}
+        node[merged_name] = m
+        return node
+
     def visit(node):
         if isinstance(node, dict):
             node = {k: visit(v) for k, v in node.items()}
             if "router" in node:          # MoE dict: expert stacks stay
                 return node
             if all(k in node for k in ("wq", "wk", "wv")):
-                m = _merge_group([node["wq"], node["wk"], node["wv"]],
-                                 "wqkv")
-                if m is not None:
-                    node = {k: v for k, v in node.items()
-                            if k not in ("wq", "wk", "wv")}
-                    node["wqkv"] = m
+                node = merge(node, ("wq", "wk", "wv"), "wqkv")
             if "w_gate" in node and "w_up" in node:
-                m = _merge_group([node["w_gate"], node["w_up"]],
-                                 "w_gateup")
-                if m is not None:
-                    node = {k: v for k, v in node.items()
-                            if k not in ("w_gate", "w_up")}
-                    node["w_gateup"] = m
+                node = merge(node, ("w_gate", "w_up"), "w_gateup")
             return node
         if isinstance(node, (list, tuple)):
             return type(node)(visit(v) for v in node)

@@ -13,7 +13,7 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.attention import decode_attention_step
 
-B, H, KV, HD, S = 3, 4, 2, 16, 24
+B, H, KV, HD, S = 3, 4, 2, 16, 48
 
 
 def _rand(rng, *shape):
@@ -102,8 +102,9 @@ def test_twin_bit_identical_to_generic_path(qk_norm, theta, window):
 @pytest.mark.parametrize("per_slot", [False, True])
 @pytest.mark.parametrize("qk_norm,theta,window,block_s", [
     (True, 10000.0, None, 128),
-    (True, 10000.0, None, 8),      # multi-tile online softmax
-    (False, 500.0, 6, 4),
+    (True, 10000.0, None, 16),     # multi-tile online softmax
+    (True, 10000.0, None, 32),     # ragged last tile (48 = 32 + 16 rows)
+    (False, 500.0, 6, 16),
     (False, 0.0, None, 128),
 ])
 def test_pallas_kernel_matches_twin(per_slot, qk_norm, theta, window,
@@ -126,6 +127,23 @@ def test_pallas_kernel_matches_twin(per_slot, qk_norm, theta, window,
                                rtol=0, atol=2e-6)
     np.testing.assert_array_equal(np.asarray(ck_p), np.asarray(ck_t))
     np.testing.assert_array_equal(np.asarray(cv_p), np.asarray(cv_t))
+
+
+def test_ragged_last_tile_matches_twin():
+    """Positions inside a ragged last cache tile (S = 48 over 32-row
+    tiles): the rows past S that the tile covers hold no cache data and
+    must not reach the output."""
+    rng = np.random.default_rng(5)
+    q, k, v, kc, vc, _ = _inputs(rng, per_slot=True)
+    idx = jnp.asarray([S - 1, 32, 40], jnp.int32)
+    kw = dict(n_heads=H, n_kv=KV, head_dim=HD)
+    o_t, ck_t, _ = ops.decode_attention(q, k, v, kc, vc, idx,
+                                        lowering="xla", **kw)
+    o_p, ck_p, _ = ops.decode_attention(q, k, v, kc, vc, idx,
+                                        lowering="pallas", block_s=32, **kw)
+    np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_t),
+                               rtol=0, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(ck_p), np.asarray(ck_t))
 
 
 def test_per_slot_positions_match_per_request_runs():
@@ -166,10 +184,7 @@ def test_kernel_raw_entry_shapes():
     rng = np.random.default_rng(4)
     q, k, v, kc, vc, _ = _inputs(rng)
     pos = jnp.full((B,), 5, jnp.int32)
-    gains = jnp.ones((2, HD), jnp.float32)
-    out, kr, vr = decode_attention_step(
-        q.reshape(B, H, HD), k.reshape(B, KV, HD), v.reshape(B, KV, HD),
-        gains, kc, vc, pos, group=H // KV, block_s=8)
+    out = decode_attention_step(q.reshape(B, H, HD), kc, vc, pos,
+                                group=H // KV, block_s=16)
     assert out.shape == (B, H, HD) and out.dtype == jnp.float32
-    assert kr.shape == (B, KV, HD) and kr.dtype == kc.dtype
-    assert vr.shape == (B, KV, HD)
+    assert np.isfinite(np.asarray(out)).all()

@@ -3,7 +3,7 @@
 the unfused pipeline across mode (asym_u8/sym_i8) x granularity
 (per-tensor/per-channel) x plan/no-plan, through BOTH lowerings (the
 Pallas kernel in interpret mode and the blocked-XLA twin), plus the
-inference-mode STE skip and the platform-adaptive interpret default.
+inference-mode STE skip and the platform choices (kernels.platform).
 
 The exhaustive sweeps reuse the K=1 trick of tests/test_delta.py with
 IDENTITY quantizers (sx=1, zx=0): the float operands quantize to
@@ -22,7 +22,8 @@ import pytest
 from repro.core import lut as lutmod
 from repro.core.multipliers import MULTIPLIERS
 from repro.kernels import ops, ref
-from repro.kernels.approx_matmul import _resolve_interpret, delta_matmul
+from repro.kernels import platform
+from repro.kernels.approx_matmul import delta_matmul
 from repro.quant import QuantConfig, prequantize_weights, qdot
 from repro.quant import linear as qlin
 from repro.signed.multipliers import SIGNED_MULTIPLIERS
@@ -279,21 +280,39 @@ def test_inference_default_off_keeps_gradients():
 
 
 # ---------------------------------------------------------------------------
-# Platform-adaptive interpret default + K-subtile gather
+# Platform choices (kernels.platform) + K-subtile gather
 # ---------------------------------------------------------------------------
 
-def test_resolve_interpret(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert _resolve_interpret(None) == (jax.default_backend() != "tpu")
-    assert _resolve_interpret(True) is True
-    assert _resolve_interpret(False) is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert _resolve_interpret(None) is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert _resolve_interpret(None) is True
-    # explicit argument still wins over the env
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert _resolve_interpret(True) is True
+def test_platform_lowering_choice(monkeypatch):
+    """Interpret mode only on the CPU; on the TPU the qdot runs the XLA
+    twin, decode attention the Pallas kernel, and an explicit request
+    for a kernel the TPU compiler refuses raises (never interprets, never
+    swaps lowerings).  Delta tables follow the qdot lowering."""
+    monkeypatch.setattr(platform, "backend", lambda: "cpu")
+    assert platform.pallas_interpret("delta_matmul") is True
+    assert platform.lowering("qdot") == "xla"
+    assert platform.lowering("decode_attention") == "xla"
+    assert platform.lowering("qdot", "pallas") == "pallas"
+    assert platform.donate(1) == ()
+    monkeypatch.setattr(platform, "backend", lambda: "tpu")
+    assert platform.lowering("qdot") == "xla"
+    assert platform.lowering("decode_attention") == "pallas"
+    assert platform.pallas_interpret("decode_attention_step") is False
+    assert platform.delta_table_dtype() == jnp.int32
+    assert platform.donate(1) == (1,)
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        platform.pallas_interpret("fused_qdot")
+    # shapes no other test traces: the jitted kernel must trace anew
+    x = jnp.ones((3, 136), jnp.float32)
+    qw = jnp.ones((136, 40), jnp.int32)
+    with pytest.raises(NotImplementedError, match="fused_qdot"):
+        ops.fused_qdot(x, qw, jnp.asarray(ops.get_delta_lut("design2")),
+                       sx=1.0, zx=0.0, sw=1.0, zw=0.0, lowering="pallas")
+    with pytest.raises(ValueError):
+        platform.lowering("qdot", "interpret")
+    monkeypatch.setattr(platform, "backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError):
+        platform.pallas_interpret("decode_attention_step")
 
 
 @pytest.mark.parametrize("k_sub", [8, 32, 128, 999])

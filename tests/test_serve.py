@@ -94,3 +94,39 @@ def test_prequantized_weights_decode_speedup(quant_mode):
           f"raw {t_raw*1e3:.1f}ms, prequant {t_pre*1e3:.1f}ms "
           f"({t_raw/max(t_pre, 1e-9):.2f}x)")
     assert t_pre < t_raw * 1.5  # loose: CI noise must not flake this
+
+
+def test_prepare_params_leaves_serving_form():
+    """serve.prepare_params consumes the float tree (each master freed
+    once quantized, merged members freed once merged) and leaves
+    serving-form wrappers with no master; such a wrapper refuses a
+    config it cannot serve instead of requantizing from a master."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import configs
+    from repro.models import transformer as T
+    from repro.quant import QuantConfig, QuantizedWeight, qdot
+
+    args = serve.parse_args(["--arch", ARCH, "--smoke", "--calibrate", "1",
+                             "--requests", "2", "--prompt-len", "3"])
+    cfg = configs.get_smoke(ARCH)
+    qcfg = serve.quant_config(args)
+    assert qcfg.backend == "fused" and qcfg.inference
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    tree, _ = serve.prepare_params(params, cfg, qcfg, args)
+    attn = params["units"][0]["attn"]
+    assert attn["wq"].is_deleted() and attn["wo"].is_deleted()
+    assert not params["embed"].is_deleted()       # not quantized: kept
+    wrappers = [w for w in jax.tree.leaves(
+        tree, is_leaf=lambda x: isinstance(x, QuantizedWeight))
+        if isinstance(w, QuantizedWeight)]
+    assert wrappers and all(w.w is None for w in wrappers)
+    assert {"wqkv", "wo"} <= set(tree["units"][0]["attn"])
+    wo = jax.tree.map(lambda a: a[0], tree["units"][0]["attn"]["wo"])
+    x = jnp.ones((2, wo.shape[0]), jnp.float32)
+    qdot(x, wo, qcfg)                                  # serves
+    with pytest.raises(ValueError, match="no master"):
+        qdot(x, wo, QuantConfig(mode="sym_i8", inference=True))
+    with pytest.raises(ValueError, match="no master"):
+        qdot(x, wo, QuantConfig(mode=qcfg.mode))       # STE needs w

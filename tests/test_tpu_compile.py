@@ -1,0 +1,118 @@
+"""Compiles for a described TPU v5e chip (no chip attached): the kernels
+of the serving path at qwen3-1.7b's published widths, lowered the way
+kernels.platform dispatches them on the TPU.  The TPU compiler refuses
+here what it would refuse on the chip (a kernel Mosaic cannot lower, a
+block that breaks the tiling), at no chip time.  Nothing runs, so these
+tests say nothing about results or speed.
+
+The topology is described inside a module fixture: only the worker that
+runs this file loads the TPU compiler library, and every worker collects
+the same tests.  The persistent compile cache is off around the
+compiles (a compile for a described chip cannot be read back without
+one).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.kernels import ops, platform
+
+CFG = configs.get("qwen3-1.7b")
+HD = CFG.hd
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer kernels.platform to its TPU choices while tracing: the
+    process itself runs on the CPU backend."""
+    monkeypatch.setattr(platform, "backend", lambda: "tpu")
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("S", [5, 512, 2050])
+@pytest.mark.parametrize("per_slot", [False, True])
+def test_decode_attention_compiles(one_chip, on_tpu, per_slot, S):
+    """The decode-attention Pallas kernel with the bf16 caches the
+    server holds (models.layers.make_cache), through ops.decode_attention
+    (qk-norm + rope + append in XLA around the kernel).  Cache lengths:
+    one tile shorter than the bf16 sublane tile (S_max = prompt + new
+    tokens of a short request), whole tiles, and a ragged last tile."""
+    B = 4
+    H, KV = CFG.n_heads, CFG.n_kv
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    args = (s((B, 1, H, HD), jnp.float32), s((B, 1, KV, HD), jnp.float32),
+            s((B, 1, KV, HD), jnp.float32),
+            s((B, S, KV, HD), jnp.bfloat16), s((B, S, KV, HD), jnp.bfloat16),
+            s((B,) if per_slot else (), jnp.int32),
+            s((HD,), jnp.float32), s((HD,), jnp.float32))
+
+    def step(q, k, v, kc, vc, idx, qg, kg):
+        return ops.decode_attention(q, k, v, kc, vc, idx, n_heads=H,
+                                    n_kv=KV, head_dim=HD,
+                                    rope_theta=CFG.rope_theta,
+                                    q_gain=qg, k_gain=kg)
+
+    compiled = jax.jit(step).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()     # the Pallas kernel
+
+
+# (K, N) of the merged serving projections: wqkv, w_gateup, w_down
+QDOT_SHAPES = [
+    (CFG.d_model, (CFG.n_heads + 2 * CFG.n_kv) * HD),
+    (CFG.d_model, 2 * CFG.d_ff),
+    (CFG.d_ff, CFG.d_model),
+]
+
+
+@pytest.mark.parametrize("mode", ["asym_u8", "sym_i8"])
+@pytest.mark.parametrize("M", [4, 64])               # decode, prefill rows
+@pytest.mark.parametrize("K,N", QDOT_SHAPES)
+def test_fused_qdot_compiles(one_chip, on_tpu, K, N, M, mode):
+    """The fused serving qdot as the TPU dispatches it (the blocked-XLA
+    twin, see kernels.platform), per-channel scales and compensation
+    tables as the calibrated serving tree carries them."""
+    signed = mode == "sym_i8"
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+
+    def qd(x, qw, dlut, sx, zx, sw, zw, colsum, comp_r, comp_col, comp_mu):
+        return ops.fused_qdot(x, qw, dlut, sx=sx,
+                              zx=None if signed else zx, sw=sw,
+                              zw=None if signed else zw,
+                              colsum=None if signed else colsum,
+                              comp_r=comp_r, comp_col=comp_col,
+                              comp_mu=comp_mu, signed=signed,
+                              compensate=True)
+
+    args = (s((M, K), jnp.float32), s((K, N), jnp.int32),
+            s((256, 256), platform.delta_table_dtype()),
+            s((), jnp.float32), s((), jnp.float32),
+            s((1, N), jnp.float32), s((1, N), jnp.float32),
+            s((N,), jnp.float32), s((256,), jnp.float32),
+            s((N,), jnp.float32), s((), jnp.float32))
+    compiled = jax.jit(qd).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()  # the XLA twin
