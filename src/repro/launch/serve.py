@@ -48,18 +48,19 @@ queued request while the other slots keep decoding.
 
 Timing is steady-state: both steps are AOT-compiled up front and the
 compile time is reported separately (it used to be silently folded
-into the first-call tok/s).
+into the first-call tok/s).  Every time printed is an obs span (compile,
+prefill, decode; prepare_params and its stages in set-up), and the run
+ends with the obs counters (traces of each step function).
 """
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import configs, obs
 from repro.kernels import platform
 from repro.models import transformer as T
 from repro.quant import QuantConfig
@@ -84,50 +85,71 @@ def prepare_params(params, cfg, qcfg, args):
 
     Calibration draws from its OWN rng so enabling --calibrate never
     shifts the serving-prompt stream (A/B runs with and without it see
-    identical requests)."""
+    identical requests).
+
+    Each rung is an obs span under obs.PREPARE_PARAMS (prequantize,
+    calibrate > calibrate_batch + apply_calibration, attach_comp_cols,
+    fuse_projections), closed once its device work is done."""
     from repro.quant import fuse_projections, prequantize_weights
     notes = []
     wrap = args.prequantize or args.calibrate or args.plan
     if not wrap:
         return params, notes
-    params = prequantize_weights(params, qcfg, consume=True)
-    notes.append("prequantized weights"
-                 + (" (per-channel)" if qcfg.w_per_channel else ""))
-    if args.calibrate:
-        from repro.calib import apply_calibration, calibrate_decode
-        crng = np.random.default_rng(4242)
-        enc_frontend = None
-        if cfg.family == "encdec":
-            enc_frontend = crng.normal(size=(
-                args.requests, 16,
-                cfg.frontend_dim or cfg.d_model)).astype(np.float32)
-        table = None
-        for prompts in _calibration_prompts(cfg, crng, args.calibrate,
-                                            args.requests,
-                                            args.prompt_len):
-            t = calibrate_decode(params, cfg, qcfg, prompts,
-                                 gen_len=2, enc_frontend=enc_frontend)
-            table = t if table is None else table.merge(t)
-        params = apply_calibration(params, table, clip=args.clip)
-        notes.append(f"static act scales ({len(table.sites)} sites, "
-                     f"{args.calibrate} calib batches, clip={args.clip})")
-    if args.plan:
-        from repro.calib import DesignPlan, apply_plan
-        plan = DesignPlan.load(args.plan)
-        params = apply_plan(params, plan, qcfg)
-        notes.append(f"design plan {args.plan} "
-                     f"(histogram {plan.histogram()})")
-    if qcfg.backend == "fused" and qcfg.compensate:
-        # after apply_plan: plan-installed wrappers already carry their
-        # per-layer comp_col and are skipped (comp_c present)
-        from repro.calib import attach_comp_cols
-        params = attach_comp_cols(params, qcfg)
-        notes.append("fused backend (cached compensation colsums)")
-    if not args.no_fuse_proj:
-        params = fuse_projections(params, consume=True)
-        notes.append("merged wq|wk|wv -> wqkv, w_gate|w_up -> w_gateup "
-                     "(fuse_projections)")
+    with obs.span(obs.PREPARE_PARAMS):
+        with obs.span(obs.PREQUANTIZE):
+            params = jax.block_until_ready(
+                prequantize_weights(params, qcfg, consume=True))
+        notes.append("prequantized weights"
+                     + (" (per-channel)" if qcfg.w_per_channel else ""))
+        if args.calibrate:
+            with obs.span(obs.CALIBRATE):
+                params, note = _calibrate(params, cfg, qcfg, args)
+            notes.append(note)
+        if args.plan:
+            from repro.calib import DesignPlan, apply_plan
+            plan = DesignPlan.load(args.plan)
+            params = apply_plan(params, plan, qcfg)
+            notes.append(f"design plan {args.plan} "
+                         f"(histogram {plan.histogram()})")
+        if qcfg.backend == "fused" and qcfg.compensate:
+            # after apply_plan: plan-installed wrappers already carry
+            # their per-layer comp_col and are skipped (comp_c present)
+            from repro.calib import attach_comp_cols
+            with obs.span(obs.ATTACH_COMP_COLS):
+                params = jax.block_until_ready(
+                    attach_comp_cols(params, qcfg))
+            notes.append("fused backend (cached compensation colsums)")
+        if not args.no_fuse_proj:
+            with obs.span(obs.FUSE_PROJECTIONS):
+                params = jax.block_until_ready(
+                    fuse_projections(params, consume=True))
+            notes.append("merged wq|wk|wv -> wqkv, w_gate|w_up -> w_gateup "
+                         "(fuse_projections)")
     return params, notes
+
+
+def _calibrate(params, cfg, qcfg, args):
+    """Static activation scales from --calibrate batches (one
+    calibrate_batch span each) -> (params, note)."""
+    from repro.calib import apply_calibration, calibrate_decode
+    crng = np.random.default_rng(4242)
+    enc_frontend = None
+    if cfg.family == "encdec":
+        enc_frontend = crng.normal(size=(
+            args.requests, 16,
+            cfg.frontend_dim or cfg.d_model)).astype(np.float32)
+    table = None
+    for prompts in _calibration_prompts(cfg, crng, args.calibrate,
+                                        args.requests, args.prompt_len):
+        with obs.span(obs.CALIBRATE_BATCH):
+            t = calibrate_decode(params, cfg, qcfg, prompts, gen_len=2,
+                                 enc_frontend=enc_frontend)
+        table = t if table is None else table.merge(t)
+    with obs.span(obs.APPLY_CALIBRATION):
+        params = jax.block_until_ready(
+            apply_calibration(params, table, clip=args.clip))
+    return params, (f"static act scales ({len(table.sites)} sites, "
+                    f"{args.calibrate} calib batches, clip={args.clip})")
 
 
 def _scatter_slot(state, one, slot: int):
@@ -159,21 +181,26 @@ def serve_continuous(params, cfg, qcfg, args, rng):
 
     # compile + warm up all three steps before the timed serve (same
     # steady-state policy as the main path; compile gets its own line)
-    t0 = time.perf_counter()
-    warm = T.init_decode_state(cfg, B, s_max, per_slot=True)
-    tok_w, _, warm = prefill(params, warm, jnp.asarray(prompts[:B]))
-    jax.block_until_ready(serve(params, warm, tok_w)[0])
-    warm1 = T.init_decode_state(cfg, 1, s_max, per_slot=True)
-    jax.block_until_ready(
-        prefill1(params, warm1, jnp.asarray(prompts[:1]))[0])
-    del warm, warm1
-    print(f"[serve] compile+warmup: {time.perf_counter() - t0:.2f}s "
+    with obs.span(obs.COMPILE) as sp:
+        warm = T.init_decode_state(cfg, B, s_max, per_slot=True)
+        tok_w, _, warm = prefill(params, warm, jnp.asarray(prompts[:B]))
+        jax.block_until_ready(serve(params, warm, tok_w)[0])
+        warm1 = T.init_decode_state(cfg, 1, s_max, per_slot=True)
+        jax.block_until_ready(
+            prefill1(params, warm1, jnp.asarray(prompts[:1]))[0])
+        del warm, warm1
+    print(f"[serve] compile+warmup: {sp.seconds:.2f}s "
           f"(reported separately)")
 
-    t0 = time.perf_counter()
-    state = T.init_decode_state(cfg, B, s_max, per_slot=True)
-    tok, logits, state = prefill(params, state,
-                                 jnp.asarray(prompts[:B]))
+    # prefill and decode each time their own spans, closed once the
+    # tokens are ready (the host reads every step's tokens anyway)
+    t_prefill = t_decode = 0.0
+    with obs.span(obs.PREFILL) as sp:
+        state = T.init_decode_state(cfg, B, s_max, per_slot=True)
+        tok, logits, state = prefill(params, state,
+                                     jnp.asarray(prompts[:B]))
+        jax.block_until_ready(tok)
+    t_prefill += sp.seconds
     slot_req = list(range(B))                 # request id per slot
     produced = {r: [] for r in range(B)}
     next_req = B
@@ -190,30 +217,49 @@ def serve_continuous(params, cfg, qcfg, args, rng):
             while slot_req[slot] is not None and \
                     len(produced[slot_req[slot]]) >= G:
                 if next_req < N:          # slot reuse: prefill the next
-                    st1 = T.init_decode_state(cfg, 1, s_max,
-                                              per_slot=True)
-                    t1, _, st1 = prefill1(
-                        params, st1,
-                        jnp.asarray(prompts[next_req:next_req + 1]))
-                    state = _scatter_slot(state, st1, slot)
-                    tok = tok.at[slot].set(t1[0])
+                    with obs.span(obs.PREFILL) as sp:
+                        st1 = T.init_decode_state(cfg, 1, s_max,
+                                                  per_slot=True)
+                        t1, _, st1 = prefill1(
+                            params, st1,
+                            jnp.asarray(prompts[next_req:next_req + 1]))
+                        state = _scatter_slot(state, st1, slot)
+                        tok = tok.at[slot].set(t1[0])
+                        first = int(np.asarray(t1)[0, 0])
+                    t_prefill += sp.seconds
                     slot_req[slot] = next_req
-                    produced[next_req] = [int(np.asarray(t1)[0, 0])]
+                    produced[next_req] = [first]
                     next_req += 1
                 else:
                     slot_req[slot] = None
         if all(r is None for r in slot_req):
             break
-        tok, logits, state = serve(params, state, tok)
+        with obs.span(obs.DECODE) as sp:
+            tok, logits, state = serve(params, state, tok)
+            jax.block_until_ready(tok)
+        t_decode += sp.seconds
         steps += 1
-    dt = time.perf_counter() - t0
     out = np.asarray([produced[r] for r in range(N)], np.int32)
-    toks_total = N * (P + G)
+    # each request's first token comes from its prefill, the other
+    # G - 1 from decode steps
+    n_pre, n_dec = N * P, N * (G - 1)
     print(f"[serve] continuous: {N} requests over {B} slots, "
-          f"{steps} batched decode steps: {dt:.2f}s, "
-          f"{toks_total / dt:.1f} tok/s")
+          f"{steps} batched decode steps")
+    print(f"[serve] prefill: {n_pre} prompt tokens in "
+          f"{t_prefill * 1e3:.1f}ms ({n_pre / t_prefill:.1f} tok/s)")
+    print(f"[serve] decode: {n_dec} tokens in {t_decode * 1e3:.1f}ms "
+          f"({n_dec / max(t_decode, 1e-9):.1f} tok/s, "
+          f"{t_decode * 1e6 / max(steps, 1):.1f} us/step)")
     print("[serve] sample output ids:", out[0][:12].tolist())
+    _print_counters()
     return out, np.asarray(logits)
+
+
+def _print_counters() -> None:
+    """The run's counters (obs): e.g. traces of each step function, so a
+    recompile shows."""
+    print("[serve] counters:", " ".join(
+        f"{k}={v}" for k, v in sorted(obs.counters().items())))
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -312,33 +358,32 @@ def main(argv=None):
     # compile + warm up BOTH steps on a throwaway state so the loop
     # below measures steady state (first execution pays lazy init);
     # compile time is reported on its own line, not inside tok/s
-    t0 = time.perf_counter()
-    warm = T.init_decode_state(cfg, B, s_max, enc_out=enc_out)
-    if args.prefill == "fused":
-        # chain through the (possibly donated) warm state
-        _, _, warm = prefill_c(params, warm, prompts_dev)
-    jax.block_until_ready(serve_c(params, warm, tok0)[0])
-    del warm
-    t_compile = time.perf_counter() - t0
+    with obs.span(obs.COMPILE) as sp_compile:
+        warm = T.init_decode_state(cfg, B, s_max, enc_out=enc_out)
+        if args.prefill == "fused":
+            # chain through the (possibly donated) warm state
+            _, _, warm = prefill_c(params, warm, prompts_dev)
+        jax.block_until_ready(serve_c(params, warm, tok0)[0])
+        del warm
 
-    t0 = time.perf_counter()
-    if args.prefill == "fused":
-        tok, logits, state = prefill_c(params, state, prompts_dev)
-    else:
-        for i in range(args.prompt_len):
-            tok, logits, state = serve_c(params, state,
-                                         jnp.asarray(prompts[:, i:i + 1]))
-    tok.block_until_ready()
-    t_prefill = time.perf_counter() - t0
+    with obs.span(obs.PREFILL) as sp_prefill:
+        if args.prefill == "fused":
+            tok, logits, state = prefill_c(params, state, prompts_dev)
+        else:
+            for i in range(args.prompt_len):
+                tok, logits, state = serve_c(
+                    params, state, jnp.asarray(prompts[:, i:i + 1]))
+        tok.block_until_ready()
 
-    t0 = time.perf_counter()
-    generated = [tok]
-    for _ in range(args.gen_len - 1):
-        tok, logits, state = serve_c(params, state, tok)
-        generated.append(tok)
-    out = jnp.concatenate(generated, axis=1)
-    out.block_until_ready()
-    t_decode = time.perf_counter() - t0
+    with obs.span(obs.DECODE) as sp_decode:
+        generated = [tok]
+        for _ in range(args.gen_len - 1):
+            tok, logits, state = serve_c(params, state, tok)
+            generated.append(tok)
+        out = jnp.concatenate(generated, axis=1)
+        out.block_until_ready()
+    t_compile, t_prefill, t_decode = (
+        sp_compile.seconds, sp_prefill.seconds, sp_decode.seconds)
 
     n_pre = B * args.prompt_len
     n_dec = B * args.gen_len
@@ -354,6 +399,7 @@ def main(argv=None):
     print(f"[serve] {B} requests, {args.gen_len} tokens each: "
           f"{dt:.2f}s steady-state, {(n_pre + n_dec) / dt:.1f} tok/s")
     print("[serve] sample output ids:", np.asarray(out[0])[:12].tolist())
+    _print_counters()
     return np.asarray(out), np.asarray(logits)
 
 

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.quant import QuantConfig, qdot
 from .sharding import constrain
 
@@ -101,6 +102,24 @@ def attention(p, x, positions, qcfg: QuantConfig, *, n_heads: int, n_kv: int,
         else:
             k, v = cross_kv
 
+    with obs.scope(obs.ATTENTION, q):
+        out, new_cache = _attend(
+            p, q, k, v, positions, cache, n_heads=n_heads, n_kv=n_kv,
+            head_dim=head_dim, causal=causal, window=window,
+            qk_norm=qk_norm, cross_kv=cross_kv, rope_theta=rope_theta)
+    return qdot(out, p["wo"], qcfg), new_cache
+
+
+def _attend(p, q, k, v, positions, cache, *, n_heads: int, n_kv: int,
+            head_dim: int, causal: bool, window: Optional[int],
+            qk_norm: bool, cross_kv, rope_theta: float):
+    """Everything of ``attention`` between its projections: q (B, S, H,
+    hd), k and v (B, S_kv, n_kv, hd) -> (out (B, S, H * hd), new_cache).
+    ``positions`` are the queries' (attention() fills them in from the
+    cache's idx)."""
+    B, S = q.shape[:2]
+    idx = cache["idx"] if cache is not None else None
+    per_slot = idx is not None and idx.ndim == 1
     if cache is not None and S == 1 and cross_kv is None:
         # fused decode step: qk-norm + rope + cache append + masked
         # single-query attention in one lowered body (Pallas on TPU,
@@ -112,8 +131,7 @@ def attention(p, x, positions, qcfg: QuantConfig, *, n_heads: int, n_kv: int,
             rope_theta=rope_theta if rope_theta else 0.0, window=window,
             q_gain=p.get("q_norm") if qk_norm else None,
             k_gain=p.get("k_norm") if qk_norm else None)
-        new_cache = {"k": ck, "v": cv, "idx": idx + S}
-        return qdot(out, p["wo"], qcfg), new_cache
+        return out, {"k": ck, "v": cv, "idx": idx + S}
 
     if qk_norm:
         q = rmsnorm(q, p["q_norm"])
@@ -194,8 +212,7 @@ def attention(p, x, positions, qcfg: QuantConfig, *, n_heads: int, n_kv: int,
         out = jnp.moveaxis(ob, 0, 1).reshape(B, S, n_kv, group, head_dim)
     else:
         out = attend(qg, qpos)
-    out = out.reshape(B, S, n_heads * head_dim)
-    return qdot(out, p["wo"], qcfg), new_cache
+    return out.reshape(B, S, n_heads * head_dim), new_cache
 
 
 def make_cache(batch: int, s_max: int, n_kv: int, head_dim: int,
@@ -261,6 +278,7 @@ def unembed(table, x, qcfg: QuantConfig):
     """Tied output head.  Exact by default (QuantConfig.quant_unembed);
     routing it through the approximate multiplier is supported but
     memory-hostile at 256k vocabs (see EXPERIMENTS.md §Perf)."""
-    if not qcfg.quant_unembed:
-        return jnp.matmul(x, table.T)
-    return qdot(x, table.T, qcfg)
+    with obs.scope(obs.UNEMBED, x):
+        if not qcfg.quant_unembed:
+            return jnp.matmul(x, table.T)
+        return qdot(x, table.T, qcfg)

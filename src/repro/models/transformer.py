@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.calib.observe import pscan
 from repro.quant import QuantConfig
 from . import layers, moe as moe_mod, recurrent
@@ -382,15 +383,18 @@ def forward_decode(params, state, tokens, cfg: ArchConfig, qcfg: QuantConfig):
     tokens one by one (tests/test_prefill.py).  state from
     init_decode_state."""
     B, S = tokens.shape
-    x = layers.embed(params["embed"], tokens)
+    with obs.scope(obs.EMBED, tokens):
+        x = layers.embed(params["embed"], tokens)
     positions = None  # decode positions come from caches (idx)
     cross_ctx = state.get("enc_out")
     if cfg.family == "encdec":
         cross_ctx = state["enc_out"]
-    x, new_caches, _ = _decoder_stack(
-        params, x, positions, cfg, qcfg, caches=state["caches"],
-        cross_ctx=cross_ctx)
-    x = layers.rmsnorm(x, params["final_norm"])
+    with obs.scope(obs.LAYERS, x):
+        x, new_caches, _ = _decoder_stack(
+            params, x, positions, cfg, qcfg, caches=state["caches"],
+            cross_ctx=cross_ctx)
+    with obs.scope(obs.FINAL_NORM, x):
+        x = layers.rmsnorm(x, params["final_norm"])
     logits = layers.unembed(params["embed"], x, qcfg)
     new_state = dict(state, caches=new_caches)
     return logits, new_state

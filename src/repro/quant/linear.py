@@ -49,6 +49,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ops
 from .quantize import QuantConfig, quantize_int8, quantize_uint8
 
@@ -441,9 +442,16 @@ def qdot(x: jax.Array, w: jax.Array, cfg: QuantConfig) -> jax.Array:
     x: (..., K) float; w: (K, N) float master weights, or a
     QuantizedWeight (prequantize_weights / repro.calib) carrying any of:
     cached weight quantization, calibrated static activation scales, a
-    per-layer design plan (delta table).
+    per-layer design plan (delta table).  Runs under the device scope
+    qdot.<last part of the wrapper's path> (obs.qdot_scope).
     """
     pre = w if isinstance(w, QuantizedWeight) else None
+    with obs.scope(obs.qdot_scope(pre.path if pre is not None else ""),
+                   x):
+        return _qdot(x, w, pre, cfg)
+
+
+def _qdot(x, w, pre, cfg: QuantConfig):
     if pre is not None:
         w = pre.w
         if pre.mode != cfg.mode or (

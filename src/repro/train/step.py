@@ -17,6 +17,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import transformer as T
 from repro.models.transformer import ArchConfig
 from repro.quant import QuantConfig
@@ -76,14 +77,21 @@ def make_train_step(cfg: ArchConfig, qcfg: QuantConfig, ocfg: OptConfig,
     return train_step
 
 
+def _greedy(logits):
+    """The next token of each row: argmax of the last position's logits."""
+    with obs.scope(obs.SAMPLE, logits):
+        return jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+
+
 def make_serve_step(cfg: ArchConfig, qcfg: QuantConfig):
     """One batched decode step: (params, state, tokens) -> (logits, state).
 
-    Greedy sampling included so the example driver can loop it."""
+    Greedy sampling included so the example driver can loop it.  Each
+    trace (not call) of the step adds one to obs.TRACES_SERVE_STEP."""
     def serve_step(params, state, tokens):
+        obs.count(obs.TRACES_SERVE_STEP)
         logits, state = T.forward_decode(params, state, tokens, cfg, qcfg)
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        return next_tok, logits, state
+        return _greedy(logits), logits, state
     return serve_step
 
 
@@ -103,15 +111,16 @@ def make_prefill_step(cfg: ArchConfig, qcfg: QuantConfig):
     (QuantConfig.act_per_pos): each sequence slice quantizes over the
     same (B, 1, K) block the token loop would, so uncalibrated serving
     is also bit-identical to the loop.  Static/calibrated trees ignore
-    the flag (their scales are fixed per layer already)."""
+    the flag (their scales are fixed per layer already).  Each trace adds
+    one to obs.TRACES_PREFILL_STEP."""
     import dataclasses
     qcfg_prefill = dataclasses.replace(qcfg, act_per_pos=True)
 
     def prefill_step(params, state, tokens):
+        obs.count(obs.TRACES_PREFILL_STEP)
         logits, state = T.forward_decode(params, state, tokens, cfg,
                                          qcfg_prefill)
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        return next_tok, logits, state
+        return _greedy(logits), logits, state
     return prefill_step
 
 

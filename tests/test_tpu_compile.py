@@ -116,3 +116,50 @@ def test_fused_qdot_compiles(one_chip, on_tpu, K, N, M, mode):
             s((N,), jnp.float32), s((), jnp.float32))
     compiled = jax.jit(qd).lower(*args).compile()
     assert "tpu_custom_call" not in compiled.as_text()  # the XLA twin
+
+
+@pytest.fixture(scope="module")
+def smoke_serving_tree():
+    """The smoke serving tree as the server prepares it (prequantized,
+    calibrated, fused backend, merged projections), built on the CPU
+    before any test steers the platform to its TPU choices."""
+    from repro.launch import serve
+    from repro.models import transformer as T
+    args = serve.parse_args(["--smoke", "--calibrate", "1", "--requests",
+                             "2", "--prompt-len", "1"])
+    cfg, qcfg = configs.get_smoke("qwen3-1.7b"), serve.quant_config(args)
+    params, _ = serve.prepare_params(
+        T.init_params(jax.random.PRNGKey(0), cfg), cfg, qcfg, args)
+    state = jax.eval_shape(
+        lambda: T.init_decode_state(cfg, 2, 8, per_slot=True))
+    return cfg, qcfg, params, state
+
+
+def test_serve_step_scopes_survive_tpu_fusion(one_chip, on_tpu,
+                                              smoke_serving_tree):
+    """The smoke decode step compiled for the described v5e keeps each
+    instruction's op_name through the TPU's fusion: every fusion, loop,
+    dot and custom call resolves to a program scope (repro.obs), and the
+    decode-attention kernel sits under the attention scope."""
+    import re
+
+    from repro import obs
+    from repro.train import make_serve_step
+    cfg, qcfg, params, state = smoke_serving_tree
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    hlo = jax.jit(make_serve_step(cfg, qcfg)).lower(
+        place(params), place(state),
+        _spec(one_chip, (2, 1), jnp.int32)).compile().as_text()
+    table = obs.scopes_of_hlo(hlo)
+    runs = re.compile(r" (fusion|while|dot|custom-call|convolution)\(")
+    ops = {m.group(2): line for line in hlo.splitlines()
+           if (m := obs._INSTR.match(line)) and runs.search(line)
+           and m.group(2) in table}
+    assert ops and all(table[n] != obs.UNSCOPED for n in ops), \
+        [n for n in ops if table[n] == obs.UNSCOPED][:5]
+    kernel = [n for n, line in ops.items() if "tpu_custom_call" in line]
+    assert kernel and {table[n] for n in kernel} == {obs.ATTENTION}
+    assert {obs.EMBED, obs.LAYERS, obs.ATTENTION, obs.FINAL_NORM,
+            obs.UNEMBED, obs.SAMPLE, "qdot.wqkv", "qdot.wo",
+            "qdot.w_gateup", "qdot.w_down"} <= set(table.values())
