@@ -10,11 +10,11 @@ Cells (selection rationale in EXPERIMENTS.md):
   C qwen3-1.7b     train_4k    — paper-technique cell (backend sweep)
 
 Also hosts the delta-kernel block-shape autotuner (``--autotune-delta``):
-sweeps (TM, TN, TK) for kernels.approx_matmul.delta_matmul AND the
-fused serving kernel's (TM, TN, TK, TKsub) space (ops.fused_qdot, per
-quant mode) on a fixed matmul shape, recording the winners to
+sweeps (TM, TN, TK) for kernels.approx_matmul.delta_matmul and times
+the fused serving qdot (ops.fused_qdot, per quant mode: the one-hot
+kernel and the twin's k_block) on a fixed matmul shape, recording to
 experiments/delta_autotune.json; and the serving-step tuner
-(``--autotune-serve``): the fused kernel's point at the PREFILL shape
+(``--autotune-serve``): the fused qdot at the PREFILL shape
 (M = B·S — a new tile regime: tall activations against the same
 weights) plus the decode-attention kernel's cache-tile (block_s) space
 (kernels.attention.decode_attention_step).
@@ -43,10 +43,6 @@ DELTA_BLOCK_CANDIDATES = [
 
 
 DELTA_REF_KB_CANDIDATES = [8, 16, 32, 64]
-
-# K-subtile sizes for the stage-2 gather loop: the live index surface is
-# TM*TKsub*TN * 2 B, so 32 at 128x128 out tiles is a 1 MiB gather buffer.
-FUSED_KSUB_CANDIDATES = [16, 32, 64, 128]
 
 
 def autotune_delta(shape=(256, 256, 256), design: str = "design2",
@@ -128,11 +124,11 @@ def autotune_delta(shape=(256, 256, 256), design: str = "design2",
 
 def autotune_fused(shape=(256, 256, 256), design: str = "design2",
                    out: str = "experiments/delta_autotune.json"):
-    """Learn the fused serving kernel's (TM, TN, TK, TKsub) space per
-    quant mode (asym_u8 / sym_i8) and the XLA twin's k_block, recording
-    the winners to ``out``.  Off-TPU the Pallas sweep runs in interpret
-    mode — the relative tile ordering is the point; re-run on hardware
-    for real numbers."""
+    """Time the fused serving qdot's one-hot kernel (its blocks come
+    from the shape) per quant mode (asym_u8 / sym_i8) and learn the XLA
+    twin's k_block, recording the results to ``out``.  Off-TPU the
+    kernel runs in interpret mode: re-run on hardware for real
+    numbers."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -174,20 +170,11 @@ def autotune_fused(shape=(256, 256, 256), design: str = "design2",
                 x, qw, dlut, sx=sx, zx=zx, sw=sw, zw=zw, colsum=colsum,
                 signed=signed, lowering=lowering, **kw))
 
-        blocks = [blk for blk in DELTA_BLOCK_CANDIDATES
-                  if blk[0] <= M and blk[1] <= N and blk[2] <= K] \
-            or [min(DELTA_BLOCK_CANDIDATES,
-                    key=lambda blk: blk[0] * blk[1] * blk[2])]
-        pallas_results = []
-        for block in blocks:
-            for ks in [k for k in FUSED_KSUB_CANDIDATES
-                       if k <= block[2] and block[2] % k == 0]:
-                f = fused("pallas", block=block, k_sub=ks)
-                us = bench_us(lambda: f(x, qw), reps=3)
-                pallas_results.append({"block": list(block), "k_sub": ks,
-                                       "us_per_call": round(us, 1)})
-                print(f"  fused[{mode}] pallas block={block} "
-                      f"k_sub={ks}: {us:.0f} us")
+        # the one-hot kernel takes its blocks from the shape: one point
+        f = fused("pallas")
+        us = bench_us(lambda: f(x, qw), reps=3)
+        pallas_results = [{"us_per_call": round(us, 1)}]
+        print(f"  fused[{mode}] pallas (one-hot): {us:.0f} us")
         kbs = [kb for kb in DELTA_REF_KB_CANDIDATES if K % kb == 0] \
             or [next(kb for kb in (32, 16, 8, 4, 2, 1) if K % kb == 0)]
         xla_results = []
@@ -208,8 +195,7 @@ def autotune_fused(shape=(256, 256, 256), design: str = "design2",
         }
         records.append(rec)
         pb = rec["pallas"]["best"]
-        print(f"[autotune] fused {mode} {design} {M}x{K}x{N}: pallas best="
-              f"{tuple(pb['block'])} k_sub={pb['k_sub']} "
+        print(f"[autotune] fused {mode} {design} {M}x{K}x{N}: pallas "
               f"({pb['us_per_call']:.0f} us), xla best "
               f"kb={rec['xla']['best']['k_block']} "
               f"({rec['xla']['best']['us_per_call']:.0f} us)")

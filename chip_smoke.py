@@ -41,10 +41,9 @@ ARCH = "qwen3-1.7b"
 SEED = 0
 DESIGN = "design2"
 # The width and depth are the published ones; the traffic is cut to fit
-# the run in 20 minutes.  On the TPU the qdot's blocked-XLA twin gathers
-# one delta-table entry per MAC, at about 0.14 G lookups/s on a v5e, so
-# a full-depth decode step at M = 2 takes about 20 s, and every prompt
-# row costs as much again (eager calibration included).
+# the run in 20 minutes: the eager calibration runs the qdot's
+# blocked-XLA twin, which gathers one delta-table entry per MAC (about
+# 0.14 G lookups/s on a v5e), so each prompt row costs seconds there.
 REQUESTS, PROMPT_LEN, GEN_LEN = 2, 2, 3
 # Phase (c): the kernel and the twin both contract in f32 at HIGHEST
 # precision; they differ by f32 reassociation (online against two-pass
@@ -235,7 +234,7 @@ def main() -> int:
     from repro import configs
     cfg = configs.get(ARCH)
     print("[chip_smoke] lowerings: " + json.dumps({
-        "qdot (delta/fused backends)": platform.lowering("qdot"),
+        "qdot (fused backend)": platform.lowering("qdot"),
         "decode attention": platform.lowering("decode_attention"),
         "prefill attention": "xla (models.layers.attention)",
         "unembed": "xla (f32 matmul)"}), flush=True)

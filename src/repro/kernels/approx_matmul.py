@@ -2,29 +2,28 @@
 
 Four kernels:
 
-  * ``fused_qdot``     — the fused serving path: float activations in,
-    float32 out.  One kernel body does (1) static-scale activation
-    quantization (scales/zero-points ride as SMEM scalar operands, from
-    repro.calib.static), (2) the two-stage exact-int32-dot + int16 delta
-    gather, with the delta table a **kernel operand** (not a Python
-    closure) so per-layer plan tables sliced out of a jax.lax.scan ride
-    the same jitted body, and (3) a dequant epilogue folding the scale
-    product, zero-point cross terms (asym_u8), and the mean-field
-    compensation tables into the output tile before it leaves VMEM.
+  * ``onehot_qdot``    — the fused serving qdot, what the TPU runs
+    (kernels.ops.fused_qdot): float activations in, float32 out, one
+    pallas_call.  It quantizes the activations with the static scale,
+    selects their rows of the approximate product table P = a*b + D (D
+    the delta table, or the selected table of a bank) split into two
+    bfloat16 byte planes, contracts those rows on the MXU with a one-hot
+    of the weights that it builds in VMEM from an iota and a compare,
+    and dequantizes the tile before it leaves VMEM.  No gather anywhere,
+    bit-exact, and the same cost for every table.
 
-  * ``delta_matmul``   — the two-stage integer fast path (bit-exact,
-    default ``pallas`` backend).  Mirrors the paper's two-stage
-    reduction at the kernel level: stage 1 computes the *exact* int32
-    tile product with ``jax.lax.dot`` (MXU), stage 2 gathers a compact
-    int16 delta table ``D[a,b] = approx(a,b) - a*b``
-    (core.lut.build_delta_lut, 128 KiB — half the VMEM footprint of the
-    int32 product LUT) and accumulates it on the VPU.  The gather
-    iterates K-subtiles of ``k_sub`` so the live index surface is
-    (TM, k_sub, TN) instead of the whole (TM, TK, TN) tile; the signed
-    +128 offset folds into the gather index so int8 operands need no
-    pre-shift pass.  Operands are padded to block multiples internally
-    (K-padding is corrected by subtracting the padded rows' constant
-    ``D[off,off]`` contribution).
+  * ``delta_matmul``   — the two-stage integer path (bit-exact, the
+    ``pallas`` backend).  Mirrors the paper's two-stage reduction at the
+    kernel level: stage 1 computes the *exact* int32 tile product with
+    ``jax.lax.dot`` (MXU), stage 2 gathers a compact int16 delta table
+    ``D[a,b] = approx(a,b) - a*b`` (core.lut.build_delta_lut, 128 KiB —
+    half the VMEM footprint of the int32 product LUT) and accumulates it
+    on the VPU.  The gather iterates K-subtiles of ``k_sub`` so the live
+    index surface is (TM, k_sub, TN) instead of the whole (TM, TK, TN)
+    tile; the signed +128 offset folds into the gather index so int8
+    operands need no pre-shift pass.  Operands are padded to block
+    multiples internally (K-padding is corrected by subtracting the
+    padded rows' constant ``D[off,off]`` contribution).
 
   * ``lut_matmul``   — paper-faithful legacy path (``pallas_legacy``):
     every scalar product goes through the 256x256 approximate-product
@@ -38,20 +37,20 @@ Four kernels:
     (core.lut.error_factors).  Trades bit-exactness for pure-MXU FLOPs
     (the error surface's exact rank is 247).
 
-Block shapes default to MXU-aligned (128, 128) tiles; the M/N grid axes
-are marked ``parallel`` (K stays ``arbitrary`` — the output tile is
-revisited as accumulator).  None of these four kernels builds for the
-TPU today (kernels.platform says why, and raises if one is requested
-there); on the CPU they run in interpret mode, where the tests check
-them against the blocked-XLA twins in kernels/ref.py.
+The M/N grid axes are marked ``parallel`` (K stays ``arbitrary`` — the
+output tile is revisited as accumulator).  The last three gather per
+element and do not build for the TPU (kernels.platform says why, and
+raises if one is requested there); on the CPU every kernel runs in
+interpret mode, where the tests check them against kernels/ref.py.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -168,171 +167,253 @@ def delta_matmul(a: jax.Array, b: jax.Array, dlut: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Kernel A': fused quantize -> delta -> dequant serving kernel
+# Kernel A': the fused qdot, its lookup a one-hot contraction on the MXU
 # ---------------------------------------------------------------------------
 
-def _fused_qdot_kernel(idx_ref, scal_ref, x_ref, qw_ref, dlut_ref, ntab_ref,
-                       compr_ref, out_ref, acc_ref, rs_ref, rc_ref, *,
-                       offset: int, lo: float, hi: float, asym: bool,
-                       compensate: bool, k_sub: int, K: int):
-    """Grid (M/TM, N/TN, K/TK), K innermost.
+def product_planes(dlut, layer=None, offset: int = 0):
+    """The full approximate product table of a delta table, centred and
+    split into two byte planes, each exact in bfloat16.
 
-    Scalar-prefetch operands (pltpu.PrefetchScalarGridSpec):
-      idx_ref   (1,) int32 — which table of the delta bank this call
-                uses; consumed by dlut's BlockSpec index_map, so only
-                the selected 256x256 table is DMA'd into VMEM.
-      scal_ref  (8,) f32 SMEM: [sx, zx, comp_mu, kcorr_delta,
-                kcorr_comp, pad...] — the calibrated static activation
-                quantizer plus K-padding corrections (see fused_qdot).
+    P[a + off, b + off] = a*b + D[a + off, b + off] (indexed as the twin
+    indexes D, so both operand modes work); c is the midpoint of P's
+    range, and P - c = 256*hi + lo with lo in [0, 255].  bfloat16 holds
+    both planes exactly while |P - c| < 2**16: every registered unsigned
+    and signed design (P spans at most 65,025), and any table whose
+    products span less than 2**17.  ``layer`` selects a table of a
+    stacked (L, 256, 256) bank.  A table known while tracing (not a
+    tracer) is split in numpy, so the planes are constants of the
+    program.  Returns (planes (256, 512) f32: row a holds the hi plane's
+    row a, then the lo plane's; c)."""
+    xp = jnp if isinstance(dlut, jax.core.Tracer) or layer is not None \
+        else np
+    if layer is not None:
+        dlut = jax.lax.dynamic_index_in_dim(dlut, layer, keepdims=False)
+    v = xp.arange(256, dtype=xp.int32) - offset
+    p = v[:, None] * v[None, :] + xp.asarray(dlut).astype(xp.int32)
+    c = (p.max() + p.min()) >> 1
+    p = p - c
+    return xp.concatenate([p >> 8, p & 255], axis=1).astype(xp.float32), c
+
+
+_KS = 16    # weight rows per one-hot chunk: one (16, 128) bfloat16 tile
+_TM = 32    # most activation rows per tile (the row selection unrolls them)
+_TN = 512   # most weight columns per tile
+_KB = 512   # most weight rows per grid step
+
+
+def _largest_divisor(total: int, step: int, most: int) -> int:
+    """The largest multiple of ``step`` up to ``most`` that divides
+    ``total`` (itself a multiple of ``step``)."""
+    return max(d for d in range(step, min(most, total) + 1, step)
+               if total % d == 0)
+
+
+def _onehot_blocks(M: int, K: int, N: int):
+    """Block sizes from the shapes: M rows per tile (all of M up to 32),
+    N columns per tile (up to 512), and K rows per grid step (a multiple
+    of 128 up to 512), each dividing the padded extent where it can so
+    that no operand is padded at the serving shapes.  Every buffer fits
+    the default scoped VMEM: a block's selected rows (2*tm*kb*256
+    bfloat16) at most 4 MiB, the accumulator over all of N (2*tm*N f32)
+    at most 4 MiB where tm can shrink to 8."""
+    Np = _ceil_mul(N, 128)
+    tm = M if M <= _TM else _TM
+    while tm > 8 and 2 * tm * Np * 4 > (4 << 20):
+        tm = max(8, tm // 2 // 8 * 8)
+    tn = _largest_divisor(Np, 128, _TN)
+    most = max(128, (2 << 20) // (2 * tm * 256 * 2) // 128 * 128)
+    kb = _largest_divisor(_ceil_mul(K, 128), 128, min(_KB, most))
+    return tm, tn, kb
+
+
+def _onehot_kernel(*refs, scalars, cols, offset: int, K: int, asym: bool,
+                   compensate: bool):
+    """Grid (M tiles, K blocks, N tiles), N innermost, so that the rows a
+    K block's activations select serve every N tile.
+
+    Scalar prefetch (SMEM, one (1,) ref each, named by ``scalars``): kc
+    int32 (K*c, the product table centre's share), sx, and as the mode
+    needs zx and the compensation mean mu, then the per-tensor epilogue
+    parameters of sw, zw (f32).
     Tensor operands:
-      x_ref     (TM, TK) float activations (quantized IN-kernel).
-      qw_ref    (TK, TN) int32 prequantized weights.
-      dlut_ref  (1, 256, 256) int16/int32 — the idx_ref-selected slice
-                of the delta-table BANK: per-layer plan tables are
-                kernel operands, not Python closures, so scan-sliced
-                layer indices ride this same jitted body.
-      ntab_ref  (4, TN) f32 per-output-column epilogue table:
-                rows = [sw, zw, colsum(qw), comp_col].
-      compr_ref (1, 256) f32 row compensation table mu_r.
-    Scratch: int32 accumulator tile, int32 lane-replicated rowsum,
-    f32 lane-replicated row-compensation sum.
-    """
-    k = pl.program_id(2)
+      x_ref   (tm, kb) f32 activations, quantized here.
+      qw_ref  (kb/16, 16, tn) int32 weights.
+      p_ref   (256, 512) bf16 product planes (product_planes).
+      cr_ref  (1, 256) f32 row compensation table (with compensation).
+      then one (1, tn) f32 row per per-column epilogue parameter, named
+      by ``cols`` (of sw, zw, colsum, comp_col).
+    Output: (tm, tn) f32; its index map holds the first N tile until the
+    last K block, so only finished tiles are written back.
+    Scratch: acc (N tiles, 2*tm, tn) f32, the hi and lo planes' sums;
+    r (2*tm, kb, 256) bf16, the planes' rows selected by the block's
+    activations; the rowsum of qx (tm, 1) f32 (asym); the histogram of
+    qx (tm, 256) f32 (with compensation).
+
+    At each K block's first N tile the activations select their rows of
+    both planes on the MXU (a one-hot of qx against the planes, exact:
+    one nonzero term).  Per weight row k the one-hot onehot[u, n] =
+    (qw[k, n] + off == u) is built on the VPU from an iota and a
+    compare, and only in VMEM; the MXU contracts it with the selected
+    rows, so no lookup is a gather.  The quantizer and the epilogue are
+    kernels.ref.fused_qdot_ref's op for op."""
+    ns, nc = len(scalars), len(cols)
+    sc = dict(zip(scalars, refs[:ns]))
+    x_ref, qw_ref, p_ref = refs[ns:ns + 3]
+    i = ns + 3
+    cr_ref = refs[i] if compensate else None
+    i += compensate
+    col = dict(zip(cols, refs[i:i + nc]))
+    out_ref, acc_ref, r_ref, *rest = refs[i + nc:]
+    rs_ref = rest.pop(0) if asym else None
+    hist_ref = rest.pop(0) if compensate else None
+
+    def val(name):          # a per-column row or a per-tensor scalar
+        return col[name][...] if name in col else sc[name][0]
+
+    k, j = pl.program_id(1), pl.program_id(2)
+    tm, kb = x_ref.shape
 
     @pl.when(k == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        rs_ref[...] = jnp.zeros_like(rs_ref)
-        rc_ref[...] = jnp.zeros_like(rc_ref)
+        acc_ref[j] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
 
-    sx = scal_ref[0]
-    zx = scal_ref[1]
+        @pl.when(j == 0)
+        def _init_rows():
+            if asym:
+                rs_ref[...] = jnp.zeros_like(rs_ref)
+            if compensate:
+                hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    # (1) static-scale activation quantization — same op sequence as the
-    # unfused _quantize_act_static, so quantized values are identical.
-    x = x_ref[...]                                      # (TM, TK) f32
-    qx = jnp.clip(jnp.round(x / sx) + zx, lo, hi).astype(jnp.int32)
-    qw = qw_ref[...].astype(jnp.int32)                  # (TK, TN)
-
-    # (2) two-stage integer product: exact MXU dot + K-subtiled delta
-    # gather against the operand table (bit-exact vs the gate level).
-    acc = acc_ref[...] + jax.lax.dot(qx, qw,
-                                     preferred_element_type=jnp.int32)
-    dlut = dlut_ref[...].reshape(-1)
-    ia = (qx + offset) & 0xFF
-    ib = (qw + offset) & 0xFF
-    acc_ref[...] = _delta_gather(acc, ia, ib, dlut, k_sub)
-
-    if asym:
-        # zero-point cross term needs rowsum(qx); int accumulation is
-        # order-free so lane-replicated partial sums stay exact.
-        rs_ref[...] = rs_ref[...] + qx.sum(axis=1, keepdims=True)
-    if compensate:
-        mu_r = compr_ref[...].reshape(-1)
-        g = mu_r.at[ia].get(mode="promise_in_bounds")
-        rc_ref[...] = rc_ref[...] + g.sum(axis=1, keepdims=True)
-
-    # (3) dequant epilogue — runs once, on the tile still in VMEM.
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _epilogue():
-        accf = acc_ref[...].astype(jnp.float32) - scal_ref[3]
-        sw = ntab_ref[0, :][None, :]
-        if compensate:
-            rowc = rc_ref[...] - scal_ref[4]
-            accf = accf - (rowc + ntab_ref[3, :][None, :]
-                           - K * scal_ref[2])
+    @pl.when(j == 0)
+    def _select_rows():
+        # ref.fused_qdot_ref's quantizer, op for op
+        q = jnp.round(x_ref[...] / sc["sx"][0])
         if asym:
-            zw = ntab_ref[1, :][None, :]
-            colsum = ntab_ref[2, :][None, :]
-            rs = rs_ref[...].astype(jnp.float32)
-            accf = accf - zw * rs - zx * colsum + K * zx * zw
-        out_ref[...] = accf * (sx * sw)
+            q = q + sc["zx"][0]
+        qx = jnp.clip(q, *((0.0, 255.0) if asym else (-128.0, 127.0)))
+        qx = qx.astype(jnp.int32)
+        qv = qx + offset                            # the table's row
+        if K % kb:          # the last block reaches past K: no row there
+            kk = k * kb + jax.lax.broadcasted_iota(jnp.int32, qx.shape, 1)
+            qx = jnp.where(kk < K, qx, 0)
+            qv = jnp.where(kk < K, qv, -1)
+        if asym:
+            rs_ref[...] += jnp.sum(qx.astype(jnp.float32), axis=1,
+                                   keepdims=True)
+        qt = jnp.transpose(qv)                      # (kb, tm)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (kb, 256), 1)
+        planes = p_ref[...]
+        for m in range(tm):
+            oh = (qt[:, m:m + 1] == lanes).astype(jnp.bfloat16)
+            rows = jax.lax.dot(oh, planes,
+                               preferred_element_type=jnp.float32)
+            r_ref[m] = rows[:, :256].astype(jnp.bfloat16)
+            r_ref[tm + m] = rows[:, 256:].astype(jnp.bfloat16)
+            if compensate:
+                hist_ref[m:m + 1, :] += jnp.sum(oh.astype(jnp.float32),
+                                                axis=0, keepdims=True)
+
+    u = jax.lax.broadcasted_iota(jnp.int32, (256, qw_ref.shape[-1]), 0)
+    u = u - offset
+
+    def chunk(c, acc):
+        w = qw_ref[c]                                       # (16, tn)
+        rc = r_ref[:, pl.ds(pl.multiple_of(c * _KS, _KS), _KS), :]
+        for jj in range(_KS):
+            onehot = (w[jj:jj + 1, :] == u).astype(jnp.bfloat16)
+            acc = acc + jax.lax.dot(rc[:, jj, :], onehot,
+                                    preferred_element_type=jnp.float32)
+        return acc
+
+    acc_ref[j] = jax.lax.fori_loop(0, kb // _KS, chunk, acc_ref[j])
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _epilogue():
+        acc = acc_ref[j]
+        prod = (acc[:tm].astype(jnp.int32) * 256
+                + acc[tm:].astype(jnp.int32) + sc["kc"][0])
+        accf = prod.astype(jnp.float32)
+        if compensate:
+            rowc = jnp.sum(hist_ref[...] * cr_ref[...], axis=1,
+                           keepdims=True)
+            accf = accf - (rowc + val("comp_col") - K * sc["mu"][0])
+        if asym:
+            zx, zw = sc["zx"][0], val("zw")
+            accf = (accf - zw * rs_ref[...] - zx * val("colsum")
+                    + K * zx * zw)
+        out_ref[...] = accf * (sc["sx"][0] * val("sw"))
 
 
-@functools.partial(jax.jit, static_argnames=("asym", "compensate", "block",
-                                             "offset", "k_sub"))
-def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array,
-               scal: jax.Array, ntab: jax.Array, comp_r: jax.Array,
-               dlut_idx: Optional[jax.Array] = None,
-               block: Tuple[int, int, int] = (128, 128, 128),
-               offset: int = 0, asym: bool = True, compensate: bool = False,
-               k_sub: int = 32) -> jax.Array:
-    """Fused quantized-linear: float x (M, K) -> float32 y (M, N).
+@functools.partial(jax.jit, static_argnames=("offset", "asym", "compensate"))
+def onehot_qdot(x: jax.Array, qw: jax.Array, planes, scalars: dict,
+                cols: dict, comp_r=None, *, offset: int = 0,
+                asym: bool = True, compensate: bool = False) -> jax.Array:
+    """The fused serving qdot as one Pallas call with no gather: float
+    activations x (M, K), int32 weights qw (K, N) -> float32 (M, N).
 
-    One pallas_call quantizes the activations with the calibrated STATIC
-    (scale, zp) carried in ``scal``, runs the two-stage exact-dot +
-    delta-gather against ``dlut``, and dequantizes in a VMEM epilogue
-    folding scale product, zero-point cross terms and compensation
-    tables.  ``dlut`` is a (256, 256) table or a STACKED (L, 256, 256)
-    bank with ``dlut_idx`` a scalar int32 layer index: the index rides
-    scalar-prefetch and the table's BlockSpec index_map selects which
-    256x256 table to DMA — per-layer plan tables are kernel operands,
-    and only the selected 128 KiB slice ever reaches VMEM.  Use
-    kernels.ops.fused_qdot for the normalized entry point (operand
-    packing + platform-adaptive lowering).
+    planes: product_planes' (256, 512) table (exact in bfloat16).
+    scalars: name -> (1,) array: "kc" int32 (K*c), "sx", and as the mode
+    needs "zx" (asym) and "mu" (compensation), then "sw"/"zw" when
+    per-tensor (f32).  cols: name -> (1, N) f32 per-column epilogue
+    parameters (of "sw", "zw", "colsum", "comp_col").  comp_r: the
+    (256,) row compensation table, with ``compensate``.
 
-    scal: (8,) f32 [sx, zx, comp_mu, 0, 0, pad...] — positions 3/4 are
-    OVERWRITTEN here with the K-padding corrections
-    (Kp-K)·D[qx0+off, off] and (Kp-K)·mu_r[qx0+off] where qx0 = 0 is
-    arranged by padding x with -zx·sx (which quantizes to exactly 0).
-    ntab: (4, N) f32 rows [sw, zw, colsum, comp_col].
+    The kernel quantizes x with the static scale, sums P[qx + off, qw +
+    off] over K as a one-hot contraction on the MXU (exact: every
+    plane's sum is an integer below 2**24 for K < 65,536) and applies
+    the dequant epilogue before the tile leaves VMEM.  Its cost is the
+    same whatever the table holds, and at the serving shapes no operand
+    is padded or prepared in XLA: the call is the projection's only
+    device op.
     """
     M, K = x.shape
-    K2, N = qw.shape
-    assert K == K2, (x.shape, qw.shape)
-    if dlut.ndim == 2:
-        dlut = dlut[None]
-    if dlut_idx is None:
-        dlut_idx = jnp.int32(0)
-    idx = dlut_idx.astype(jnp.int32).reshape((1,))
-    TM, TN, TK = block
-    k_sub = _sub_divisor(TK, k_sub)
-    Mp, Kp, Np = _ceil_mul(M, TM), _ceil_mul(K, TK), _ceil_mul(N, TN)
-    lo, hi = (0.0, 255.0) if asym else (-128.0, 127.0)
-
-    sx, zx = scal[0], scal[1]
-    x0 = -zx * sx          # quantizes to exactly 0 (zx is integer-valued)
-    xp = jnp.full((Mp, Kp), x0, jnp.float32)
-    xp = jax.lax.dynamic_update_slice(xp, x.astype(jnp.float32), (0, 0))
-    qwp = _pad_to(qw.astype(jnp.int32), Kp, Np)
-    ntabp = _pad_to(ntab.astype(jnp.float32), 4, Np)
-    # K-padding corrections: padded (qx, qw) pairs are (0, 0), so the
-    # gathers add (Kp-K) copies of D[off, off] / mu_r[off].
-    kpad = jnp.float32(Kp - K)
-    scal = scal.astype(jnp.float32)
-    scal = scal.at[3].set(
-        kpad * dlut[idx[0], offset, offset].astype(jnp.float32))
-    scal = scal.at[4].set(kpad * comp_r.reshape(-1)[offset])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # idx (int32), scal (f32) refs
-        grid=(Mp // TM, Np // TN, Kp // TK),
-        in_specs=[
-            pl.BlockSpec((TM, TK), lambda i, j, k, ir, sr: (i, k)),   # x
-            pl.BlockSpec((TK, TN), lambda i, j, k, ir, sr: (k, j)),   # qw
-            pl.BlockSpec((1, 256, 256),
-                         lambda i, j, k, ir, sr: (ir[0], 0, 0)),      # dlut
-            pl.BlockSpec((4, TN), lambda i, j, k, ir, sr: (0, j)),    # ntab
-            pl.BlockSpec((1, 256), lambda i, j, k, ir, sr: (0, 0)),   # mu_r
-        ],
-        out_specs=pl.BlockSpec((TM, TN), lambda i, j, k, ir, sr: (i, j)),
-        scratch_shapes=[
-            pltpu.VMEM((TM, TN), jnp.int32),    # integer accumulator
-            pltpu.VMEM((TM, 1), jnp.int32),     # rowsum(qx)
-            pltpu.VMEM((TM, 1), jnp.float32),   # rowsum(mu_r[qx])
-        ],
-    )
+    N = qw.shape[1]
+    tm, tn, kb = _onehot_blocks(M, K, N)
+    Mp, Kp, Np = _ceil_mul(M, tm), _ceil_mul(K, kb), _ceil_mul(N, tn)
+    nk = Kp // kb
+    x = _pad_to(x.astype(jnp.float32), Mp, Kp)
+    qw = _pad_to(qw.astype(jnp.int32), Kp, Np).reshape(Kp // _KS, _KS, Np)
+    cols = {n: _pad_to(v, 1, Np) for n, v in cols.items()}
+    snames, cnames = tuple(scalars), tuple(cols)
+    in_specs = [
+        pl.BlockSpec((tm, kb), lambda i, k, j, *_: (i, k)),
+        pl.BlockSpec((kb // _KS, _KS, tn), lambda i, k, j, *_: (k, 0, j)),
+        pl.BlockSpec((256, 512), lambda i, k, j, *_: (0, 0)),
+    ]
+    operands = [x, qw, jnp.asarray(planes, jnp.bfloat16)]
+    if compensate:
+        in_specs.append(pl.BlockSpec((1, 256), lambda i, k, j, *_: (0, 0)))
+        operands.append(jnp.asarray(comp_r, jnp.float32).reshape(1, 256))
+    in_specs += [pl.BlockSpec((1, tn), lambda i, k, j, *_: (0, j))
+                 for _ in cnames]
+    operands += [cols[n] for n in cnames]
+    scratch = [pltpu.VMEM((Np // tn, 2 * tm, tn), jnp.float32),
+               pltpu.VMEM((2 * tm, kb, 256), jnp.bfloat16)]
+    if asym:
+        scratch.append(pltpu.VMEM((tm, 1), jnp.float32))
+    if compensate:
+        scratch.append(pltpu.VMEM((tm, 256), jnp.float32))
     out = pl.pallas_call(
-        functools.partial(_fused_qdot_kernel, offset=offset, lo=lo, hi=hi,
-                          asym=asym, compensate=compensate, k_sub=k_sub,
-                          K=K),
-        grid_spec=grid_spec,
+        functools.partial(_onehot_kernel, scalars=snames, cols=cnames,
+                          offset=offset, K=K, asym=asym,
+                          compensate=compensate),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(snames),
+            grid=(Mp // tm, nk, Np // tn),
+            in_specs=in_specs,
+            # an output block is written back when the index changes:
+            # before the last K block every step names the first tile
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda i, k, j, *_: (i, jnp.where(k == nk - 1,
+                                                            j, 0))),
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         compiler_params=platform.compiler_params(
-            "parallel", "parallel", "arbitrary"),
-        interpret=platform.pallas_interpret("fused_qdot"),
-    )(idx, scal, xp, qwp, dlut, ntabp, comp_r.reshape(1, 256))
-    return out[:M, :N]
+            "parallel", "arbitrary", "arbitrary"),
+        interpret=platform.pallas_interpret("onehot_qdot"),
+        name="onehot_qdot",
+    )(*[scalars[n] for n in snames], *operands)
+    return out[:M, :N] if (Mp, Np) != (M, N) else out
 
 
 # ---------------------------------------------------------------------------

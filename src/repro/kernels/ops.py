@@ -3,17 +3,18 @@
 ``approx_matmul`` is the operator the quantized layers call.  Backends:
 
   'delta'    — the two-stage fast path (bit-exact, recommended): exact
-               int32 product on the MXU + delta-table gather.  Lowered
-               as kernels.platform chooses: the blocked-XLA twin on
-               every platform today (the Pallas kernel's gather does
-               not build for the TPU).  Any shape; the signed offset
-               folds into the gather index (no operand pre-shift).
-  'fused'    — the fused quantize->delta->dequant serving kernel
-               (``fused_qdot`` below).  quant.linear dispatches to it
-               when a QuantizedWeight carries calibrated static
-               activation scales; integer-operand approx_matmul calls
-               with backend='fused' fall back to 'delta' (same integer
-               core, nothing to fuse without the float ends).
+               int32 product + delta-table gather, as the blocked-XLA
+               twin (ref.delta_matmul_ref) on every platform (the
+               Pallas kernel's gather does not build for the TPU).  Any
+               shape; the signed offset folds into the gather index (no
+               operand pre-shift).
+  'fused'    — the fused quantize->product->dequant serving path
+               (``fused_qdot`` below; one one-hot kernel call on the
+               TPU).  quant.linear dispatches to it when a
+               QuantizedWeight carries calibrated static activation
+               scales; integer-operand approx_matmul calls with
+               backend='fused' fall back to 'delta' (same integer core,
+               nothing to fuse without the float ends).
   'pallas'   — the delta Pallas kernel explicitly (interpret mode on
                the CPU, what the kernel tests exercise; refused on the
                TPU).
@@ -44,9 +45,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from . import platform, ref
 from .approx_matmul import delta_matmul, lut_matmul, residual_matmul
-from .approx_matmul import fused_qdot as _fused_qdot_pallas
+from .approx_matmul import onehot_qdot, product_planes
 
 _LUT_CACHE: dict = {}
 
@@ -132,9 +135,8 @@ def _approx_matmul_fwd_impl(a, b, design, backend, rank, signed=False):
         # Signed operands index the table via the folded-in offset; no
         # pre-shift pass, and shapes need not be block multiples.
         # 'delta' (and 'fused', which on integer operands has no float
-        # ends to fuse) takes the platform's qdot lowering.
-        if backend == "pallas" or (backend in ("delta", "fused")
-                                   and platform.lowering("qdot") == "pallas"):
+        # ends to fuse) runs the XLA twin; 'pallas' the gather kernel.
+        if backend == "pallas":
             out = delta_matmul(a2, b,
                                jnp.asarray(get_delta_lut(design, signed)),
                                offset=off)
@@ -256,51 +258,74 @@ def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array, *,
                dlut_idx=None, sx, zx=None, sw, zw=None, colsum=None,
                comp_r=None, comp_col=None, comp_mu=None,
                signed: bool = False, compensate: bool = False,
-               block=(128, 128, 128), k_sub: int = 32, k_block: int = 32,
-               lowering: str = "auto") -> jax.Array:
+               k_block: int = 32, lowering: str = "auto") -> jax.Array:
     """The fused serving qdot: float x (..., K) @ prequantized qw (K, N)
     -> float32 (..., N), with static-scale activation quantization, the
-    two-stage delta product (``dlut`` as an operand), and the dequant
-    epilogue in one lowered body.
+    approximate integer product (``dlut`` as an operand), and the
+    dequant epilogue.
 
     dlut: (256, 256) delta table, or a stacked (L, 256, 256) BANK with
     ``dlut_idx`` a scalar int32 layer index (the mixed-design plan
     path: quant.linear.register_dlut_bank keeps the bank out of the
-    layer scan; the index selects the table via scalar-prefetch on the
-    Pallas lowering and a folded gather base on the XLA twin).
-    sx/zx: calibrated static activation scale / zero point (zx None for
-    sym_i8).  sw/zw: weight scale / zero point — scalar (per-tensor) or
-    (1, N)/(N,) (per-channel).  colsum: colsum(qw) for the asym_u8
-    zero-point cross term.  comp_*: mean-field compensation tables
-    (row table (256,), precomputed column colsum (N,), scalar mean)
-    when ``compensate``.  ``lowering``: 'auto' (kernels.platform: the
-    blocked-XLA twin on every platform today), 'pallas' (the Pallas
-    kernel — interpret mode on the CPU, refused on the TPU), or 'xla'.
+    layer scan; the index selects the table before the one-hot kernel
+    splits it into planes, and folds into the gather base on the XLA
+    twin).  sx/zx: calibrated static activation scale / zero point (zx
+    None for sym_i8).  sw/zw: weight scale / zero point — scalar
+    (per-tensor) or (1, N)/(N,) (per-channel).  colsum: colsum(qw) for
+    the asym_u8 zero-point cross term.  comp_*: mean-field compensation
+    tables (row table (256,), precomputed column colsum (N,), scalar
+    mean) when ``compensate``.  ``lowering`` (kernels.platform): 'auto'
+    (the one-hot contraction on the TPU, the blocked-XLA twin
+    elsewhere), 'pallas' (the one-hot kernel, in interpret mode on the
+    CPU) or 'xla' (the twin, ``k_block`` K rows per gather step).  The
+    kernel applies the twin's quantizer and epilogue
+    (ref.fused_qdot_ref) op for op; the integer product is bit-exact in both,
+    and with compensation the row table's sum (a histogram of qx against
+    the table in the kernel) differs by float reassociation only.  Each call traced under a
+    step adds one to obs.QDOT_LOWERING_ONEHOT or obs.QDOT_LOWERING_XLA.
     """
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = qw.shape[-1]
     x2 = x.reshape(-1, K)
     off = 128 if signed else 0
-    scal = jnp.stack([jnp.asarray(sx, jnp.float32).reshape(()),
-                      (jnp.asarray(zx, jnp.float32).reshape(())
-                       if zx is not None else jnp.float32(0.0)),
-                      (jnp.asarray(comp_mu, jnp.float32).reshape(())
-                       if comp_mu is not None else jnp.float32(0.0)),
-                      jnp.float32(0.0), jnp.float32(0.0),    # kpad corr slots
-                      jnp.float32(0.0), jnp.float32(0.0), jnp.float32(0.0)])
-    ntab = jnp.stack([_as_col(sw, N), _as_col(zw, N),
-                      _as_col(colsum, N), _as_col(comp_col, N)])
-    cr = (jnp.asarray(comp_r, jnp.float32).reshape(-1) if comp_r is not None
-          else jnp.zeros((256,), jnp.float32))
     layer = (jnp.asarray(dlut_idx, jnp.int32).reshape(())
              if dlut_idx is not None else None)
-    if platform.lowering("qdot", lowering) == "pallas":
-        out = _fused_qdot_pallas(x2, qw, jnp.asarray(dlut), scal, ntab, cr,
-                                 dlut_idx=layer, block=tuple(block),
-                                 offset=off, asym=not signed,
-                                 compensate=compensate, k_sub=k_sub)
+    onehot = platform.lowering("qdot", lowering) == "pallas"
+    if isinstance(x, jax.core.Tracer):
+        obs.count(obs.QDOT_LOWERING_ONEHOT if onehot
+                  else obs.QDOT_LOWERING_XLA)
+    if onehot:
+        planes, c = product_planes(dlut, layer, off)
+        scalars = {"kc": jnp.asarray(K * c, jnp.int32).reshape(1)}
+        cols = {}
+
+        def param(name, v):
+            # as it comes (reshapes only), so that no op prepares it
+            v = jnp.asarray(0.0 if v is None else v, jnp.float32)
+            if v.size == 1:
+                scalars[name] = v.reshape(1)
+            else:
+                cols[name] = v.reshape(1, N)
+        param("sx", sx)
+        param("sw", sw)
+        if not signed:
+            for name, v in (("zx", zx), ("zw", zw), ("colsum", colsum)):
+                param(name, v)
+        if compensate:
+            param("mu", comp_mu)
+            param("comp_col", comp_col)
+        out = onehot_qdot(x2, qw, planes, scalars, cols,
+                          comp_r if compensate else None, offset=off,
+                          asym=not signed, compensate=compensate)
     else:
+        scal = jnp.stack([jnp.asarray(v, jnp.float32).reshape(())
+                          for v in (sx, 0.0 if zx is None else zx,
+                                    0.0 if comp_mu is None else comp_mu)])
+        ntab = jnp.stack([_as_col(sw, N), _as_col(zw, N),
+                          _as_col(colsum, N), _as_col(comp_col, N)])
+        cr = (jnp.asarray(comp_r, jnp.float32).reshape(-1)
+              if comp_r is not None else jnp.zeros((256,), jnp.float32))
         out = ref.fused_qdot_ref(x2, qw, dlut, scal, ntab, cr, offset=off,
                                  asym=not signed, compensate=compensate,
                                  k_block=k_block, layer=layer)

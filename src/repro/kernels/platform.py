@@ -17,17 +17,20 @@ nowhere else:
   * where the persistent compile cache lives (``enable_compile_cache``,
     called by the entry points only).
 
-Why the approximate qdot runs the XLA twin on the TPU too: the Pallas
-kernels ``delta_matmul``/``fused_qdot`` gather the stage-2 delta table
-with a per-element index.  Mosaic (the TPU Pallas compiler) has no
-lowering for the ``dynamic_slice`` that walks the K-subtiles, supports
-only 2-D gathers whose source fits one vreg along the gather axis, and
-cannot gather from the flat 65,536-entry table at all.  The blocked-XLA
-twins ``ref.delta_matmul_ref``/``ref.fused_qdot_ref`` are bit-exact and
-compile for the chip, so they are what the TPU runs until a stage-2
-gather that Mosaic lowers exists.  The decode-attention kernel is made
-of 2-D dots and elementwise math, which Mosaic lowers, so the TPU runs
-it as a Pallas kernel.
+On the TPU the approximate qdot runs the one-hot contraction kernel
+(``approx_matmul.onehot_qdot``): the product table's rows, selected by
+the activations, meet a one-hot of the weights on the MXU, built in VMEM
+from an iota and a compare, so no gather is lowered at all.  The older
+kernels ``delta_matmul``, ``lut_matmul`` and ``residual_matmul`` gather
+per element, which Mosaic (the TPU Pallas compiler) does not lower: it
+has no lowering for the ``dynamic_slice`` that walks their K-subtiles,
+supports only 2-D gathers whose source fits one vreg along the gather
+axis, and cannot gather from a flat 65,536-entry table.  Off the TPU the
+qdot runs its blocked-XLA twin (``ref.fused_qdot_ref``), the one-hot
+kernel's oracle; the integer ``delta`` backend runs
+``ref.delta_matmul_ref`` everywhere.  The decode-attention kernel is
+made of 2-D dots and elementwise math, which Mosaic lowers, so the TPU
+runs it as a Pallas kernel.
 """
 from __future__ import annotations
 
@@ -40,12 +43,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Pallas kernels the TPU compiler refuses, with the reason.  An explicit
 # request for one of them on a TPU raises this reason.
-_STAGE2 = ("the stage-2 delta gather does not lower in Mosaic: no "
-           "dynamic_slice lowering for the K-subtile walk, and no gather "
-           "from the flat 65,536-entry table")
 _REFUSED_ON_TPU = {
-    "delta_matmul": _STAGE2,
-    "fused_qdot": _STAGE2,
+    "delta_matmul": "the stage-2 delta gather does not lower in Mosaic: no "
+                    "dynamic_slice lowering for the K-subtile walk, and no "
+                    "gather from the flat 65,536-entry table (the qdot's "
+                    "one-hot kernel, onehot_qdot, needs no gather)",
     "lut_matmul": "the per-k product-LUT gather does not lower in Mosaic "
                   "(only 2-D gathers within one vreg are supported)",
     "residual_matmul": "the factor-table jnp.take fails Mosaic's gather "
@@ -55,7 +57,7 @@ _REFUSED_ON_TPU = {
 # The lowering 'auto' picks for each op, per platform; every platform
 # not listed (and every op not listed) uses the XLA twin.
 _AUTO = {
-    "tpu": {"qdot": "xla", "decode_attention": "pallas"},
+    "tpu": {"qdot": "pallas", "decode_attention": "pallas"},
 }
 
 OPS = ("qdot", "decode_attention")
@@ -105,8 +107,9 @@ def delta_table_dtype():
     """Storage dtype of installed delta tables (calib.plan banks): the
     XLA twins gather from an int32 view, so the tables are pre-widened
     to int32 where the qdot runs the twin (a traced int16 table would
-    cost a 64Ki-element convert per layer per step); the int16 form
-    (None = as built) only pays off for a Pallas gather."""
+    cost a 64Ki-element convert per layer per step); the one-hot kernel
+    widens the one table it uses into its product planes, so there the
+    tables keep their built form (None, int16)."""
     return jnp.int32 if lowering("qdot") == "xla" else None
 
 

@@ -104,10 +104,11 @@ def exact_matmul_ref(a, b):
 def fused_qdot_ref(x, qw, dlut, scal, ntab, comp_r, offset: int = 0,
                    asym: bool = True, compensate: bool = False,
                    k_block: int = 32, layer=None):
-    """Blocked-XLA twin of kernels.approx_matmul.fused_qdot — the fused
-    quantize -> (exact dot + delta gather) -> dequant serving path, and
-    the lowering every platform serves with (kernels.platform says why
-    the TPU runs it too; float x in, float32 out, same operand layout).
+    """Blocked-XLA twin of the fused quantize -> (exact dot + delta
+    gather) -> dequant serving path: the oracle of the one-hot kernel
+    (kernels.approx_matmul.onehot_qdot, which repeats its quantizer and
+    epilogue op for op) and the qdot's lowering off the TPU (float x in,
+    float32 out, same operand layout as kernels.ops.fused_qdot).
 
     x: (M, K) float; qw: (K, N) int32 prequantized weights;
     dlut: (256, 256) delta table, or a stacked (L, 256, 256) bank with
@@ -123,6 +124,7 @@ def fused_qdot_ref(x, qw, dlut, scal, ntab, comp_r, offset: int = 0,
     defensive & 0xFF masks and folds the signed +128 shifts of BOTH
     operands into one compile-time index constant (offset*257): no
     per-step shift pass over the static (K, N) weight operand at all.
+    It does one scalar gather per multiply-accumulate (M*K*N of them).
 
     Every float epilogue op mirrors the unfused quant.linear pipeline's
     op sequence, so fused-vs-unfused differences stay at float-reduction

@@ -59,6 +59,10 @@ DECODE = "decode"
 # counters: traces (not calls) of the step functions
 TRACES_SERVE_STEP = "traces.serve_step"
 TRACES_PREFILL_STEP = "traces.prefill_step"
+# counters: qdot call sites traced, by the lowering kernels.ops.fused_qdot
+# chose (the one-hot contraction kernel or the blocked-XLA twin)
+QDOT_LOWERING_ONEHOT = "qdot.lowering.onehot"
+QDOT_LOWERING_XLA = "qdot.lowering.xla"
 
 MAX_SPANS = 4096
 

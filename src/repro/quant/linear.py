@@ -417,7 +417,8 @@ def _qdot_fused(x, pre, cfg: QuantConfig, signed: bool):
     elif pre.dlut is not None:
         dlut = pre.dlut
     else:
-        dlut = jnp.asarray(ops.get_delta_lut(cfg.design, signed))
+        # numpy, so a lowering may prepare it while tracing
+        dlut = ops.get_delta_lut(cfg.design, signed)
     comp_r = comp_col = comp_mu = None
     if cfg.compensate:
         comp_r, comp_c, comp_mu = _site_comp_tables(pre, cfg, signed)

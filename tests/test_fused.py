@@ -79,6 +79,94 @@ def test_fused_bank_index_selects_table(lowering):
 
 
 # ---------------------------------------------------------------------------
+# The one-hot kernel (the TPU's lowering) against the twin and the gate level
+# ---------------------------------------------------------------------------
+
+ONEHOT_TABLES = ["design2", "design1", "dadda", "random", "bank0", "bank1",
+                 "bank2"]
+BANK = ("design1", "design2", "dadda")
+
+
+def _onehot_table(name, signed, rng):
+    """(dlut, dlut_idx, elementwise product of operand values) for a
+    table of ONEHOT_TABLES: a registered design and its gate-level
+    multiplier (the signed registry's 'exact' stands for dadda), a
+    random int16 delta table over Design #2's delta range and its own
+    products, or table i of a (3, 256, 256) bank of BANK."""
+    reg = SIGNED_MULTIPLIERS if signed else MULTIPLIERS
+    design = lambda d: "exact" if signed and d == "dadda" else d  # noqa
+    if name.startswith("bank"):
+        i = int(name[4:])
+        bank = np.stack([np.asarray(ops.get_delta_lut(design(d), signed))
+                         for d in BANK]).astype(np.int16)
+        return jnp.asarray(bank), jnp.int32(i), reg[design(BANK[i])]
+    if name != "random":
+        return (jnp.asarray(ops.get_delta_lut(design(name), signed)), None,
+                reg[design(name)])
+    d2 = np.asarray(ops.get_delta_lut("design2", signed)).astype(np.int64)
+    d = rng.integers(d2.min(), d2.max() + 1, (256, 256))
+    off = 128 if signed else 0
+    return (jnp.asarray(d.astype(np.int16)), None,
+            lambda a, b: a * b + d[a + off, b + off])
+
+
+@pytest.mark.parametrize("M", [1, 4, 16, 64])
+@pytest.mark.parametrize("mode", ["asym_u8", "sym_i8"])
+@pytest.mark.parametrize("table", ONEHOT_TABLES)
+def test_onehot_kernel_bit_exact(table, mode, M):
+    """The one-hot contraction kernel (lowering 'pallas', interpret mode
+    here) gives the integer product sum_k P(qx, qw) bit for bit: equal
+    to the gate-level multiplier's (or the table's own) and to the
+    blocked-XLA twin's, for any table, bank entry, mode and M, at a K
+    and an N that are no multiple of its blocks.  With real scales,
+    zero points and per-channel columns the two lowerings' outputs are
+    equal without compensation and ULP-close with it (the row table's
+    sum is a histogram dot on the kernel's side)."""
+    signed = mode == "sym_i8"
+    rng = np.random.default_rng(M)
+    K, N = 131, 200
+    dlut, idx, product = _onehot_table(table, signed, rng)
+    lo, hi = (-128, 127) if signed else (0, 255)
+    qx = rng.integers(lo, hi + 1, (M, K))
+    qw = rng.integers(lo, hi + 1, (K, N))
+    want = product(qx[:, :, None], qw[None]).sum(1)
+    ident = dict(dlut_idx=idx, sx=1.0, sw=1.0, signed=signed,
+                 zx=None if signed else 0.0, zw=None if signed else 0.0)
+    xq, qwj = jnp.asarray(qx, jnp.float32), jnp.asarray(qw, jnp.int32)
+    got = {low: np.asarray(ops.fused_qdot(xq, qwj, dlut, lowering=low,
+                                          **ident))
+           for low in ("pallas", "xla")}
+    np.testing.assert_array_equal(got["pallas"], want.astype(np.float32))
+    np.testing.assert_array_equal(got["xla"], got["pallas"])
+
+    x = jnp.asarray(rng.normal(size=(M, K)).astype(np.float32))
+    kw = dict(dlut_idx=idx, signed=signed,
+              sw=jnp.asarray(rng.uniform(0.01, 0.02, (1, N)), jnp.float32))
+    if signed:
+        kw.update(sx=float(np.abs(x).max()) / 127.0)
+    else:
+        sx = float(x.max() - x.min()) / 255.0
+        kw.update(sx=sx, zx=float(np.round(-float(x.min()) / sx)),
+                  zw=jnp.asarray(rng.integers(100, 156, (1, N)), jnp.float32),
+                  colsum=qwj.sum(0).astype(jnp.float32))
+    mu_r, mu_c, mu = qlin._mean_field_tables("design2", signed=signed)
+    off = 128 if signed else 0
+    for compensate in (False, True):
+        if compensate:
+            kw.update(comp_r=mu_r, comp_mu=mu,
+                      comp_col=jnp.take(mu_c, qwj + off, axis=0).sum(0))
+        y = {low: np.asarray(ops.fused_qdot(x, qwj, dlut, lowering=low,
+                                            compensate=compensate, **kw))
+             for low in ("pallas", "xla")}
+        if compensate:
+            np.testing.assert_allclose(
+                y["pallas"], y["xla"], rtol=2e-6,
+                atol=2e-6 * max(np.abs(y["xla"]).max(), 1.0))
+        else:
+            np.testing.assert_array_equal(y["pallas"], y["xla"])
+
+
+# ---------------------------------------------------------------------------
 # Fused vs unfused through qdot: mode x granularity x plan/no-plan
 # ---------------------------------------------------------------------------
 
@@ -284,30 +372,33 @@ def test_inference_default_off_keeps_gradients():
 # ---------------------------------------------------------------------------
 
 def test_platform_lowering_choice(monkeypatch):
-    """Interpret mode only on the CPU; on the TPU the qdot runs the XLA
-    twin, decode attention the Pallas kernel, and an explicit request
-    for a kernel the TPU compiler refuses raises (never interprets, never
-    swaps lowerings).  Delta tables follow the qdot lowering."""
+    """Interpret mode only on the CPU; on the TPU the qdot runs the
+    one-hot kernel, decode attention the Pallas kernel, and an explicit
+    request for a kernel the TPU compiler refuses raises (never
+    interprets, never swaps lowerings).  Delta tables follow the qdot
+    lowering."""
     monkeypatch.setattr(platform, "backend", lambda: "cpu")
     assert platform.pallas_interpret("delta_matmul") is True
     assert platform.lowering("qdot") == "xla"
     assert platform.lowering("decode_attention") == "xla"
     assert platform.lowering("qdot", "pallas") == "pallas"
+    assert platform.delta_table_dtype() == jnp.int32
     assert platform.donate(1) == ()
     monkeypatch.setattr(platform, "backend", lambda: "tpu")
-    assert platform.lowering("qdot") == "xla"
+    assert platform.lowering("qdot") == "pallas"
+    assert platform.lowering("qdot", "xla") == "xla"
     assert platform.lowering("decode_attention") == "pallas"
+    assert platform.pallas_interpret("onehot_qdot") is False
     assert platform.pallas_interpret("decode_attention_step") is False
-    assert platform.delta_table_dtype() == jnp.int32
+    assert platform.delta_table_dtype() is None
     assert platform.donate(1) == (1,)
     with pytest.raises(NotImplementedError, match="Mosaic"):
-        platform.pallas_interpret("fused_qdot")
+        platform.pallas_interpret("delta_matmul")
     # shapes no other test traces: the jitted kernel must trace anew
-    x = jnp.ones((3, 136), jnp.float32)
-    qw = jnp.ones((136, 40), jnp.int32)
-    with pytest.raises(NotImplementedError, match="fused_qdot"):
-        ops.fused_qdot(x, qw, jnp.asarray(ops.get_delta_lut("design2")),
-                       sx=1.0, zx=0.0, sw=1.0, zw=0.0, lowering="pallas")
+    a = jnp.ones((3, 136), jnp.int32)
+    b = jnp.ones((136, 40), jnp.int32)
+    with pytest.raises(NotImplementedError, match="delta_matmul"):
+        ops.approx_matmul(a, b, "design2", "pallas")
     with pytest.raises(ValueError):
         platform.lowering("qdot", "interpret")
     monkeypatch.setattr(platform, "backend", lambda: "gpu")

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import configs, obs
+from repro.kernels import platform
 from repro.launch import serve
 from repro.models import transformer as T
 from repro.train import make_prefill_step, make_serve_step
@@ -90,6 +91,25 @@ def test_serve_continuous_traces_the_step_once(capsys):
     names = [s.name for s in obs.spans()]
     assert names.count(obs.PREFILL) == 2                  # B = 2, 1 refill
     assert names.count(obs.DECODE) >= 2
+
+
+def test_serve_counts_the_qdot_lowering(capsys, monkeypatch):
+    """With the qdot steered to the TPU's lowering (the one-hot kernel,
+    in interpret mode here), every qdot call site a step trace holds
+    counts once under qdot.lowering.onehot and none under the twin's."""
+    monkeypatch.setitem(platform._AUTO, "cpu",
+                        {"qdot": platform._AUTO["tpu"]["qdot"]})
+    obs.reset()
+    serve.main(["--arch", ARCH, "--smoke", "--continuous", "3",
+                "--calibrate", "1", "--requests", "2", "--prompt-len", "2",
+                "--gen-len", "3"])
+    c = obs.counters()
+    traces = c[obs.TRACES_SERVE_STEP] + c[obs.TRACES_PREFILL_STEP]
+    # q|k|v, o, gate|up, down: the merged projections, one call site
+    # each in the scanned layer body that a step trace traces once
+    assert c[obs.QDOT_LOWERING_ONEHOT] == 4 * traces
+    assert obs.QDOT_LOWERING_XLA not in c
+    assert f"qdot.lowering.onehot={4 * traces}" in capsys.readouterr().out
 
 
 def test_span_list_is_bounded():

@@ -93,9 +93,10 @@ QDOT_SHAPES = [
 @pytest.mark.parametrize("M", [4, 64])               # decode, prefill rows
 @pytest.mark.parametrize("K,N", QDOT_SHAPES)
 def test_fused_qdot_compiles(one_chip, on_tpu, K, N, M, mode):
-    """The fused serving qdot as the TPU dispatches it (the blocked-XLA
-    twin, see kernels.platform), per-channel scales and compensation
-    tables as the calibrated serving tree carries them."""
+    """The fused serving qdot as the TPU dispatches it (the one-hot
+    contraction kernel, see kernels.platform), per-channel scales and
+    compensation tables as the calibrated serving tree carries them:
+    one Pallas call, and no loop around it."""
     signed = mode == "sym_i8"
     s = lambda shape, dt: _spec(one_chip, shape, dt)
 
@@ -108,14 +109,17 @@ def test_fused_qdot_compiles(one_chip, on_tpu, K, N, M, mode):
                               comp_mu=comp_mu, signed=signed,
                               compensate=True)
 
+    table = platform.delta_table_dtype() or jnp.int16   # None: as built
     args = (s((M, K), jnp.float32), s((K, N), jnp.int32),
-            s((256, 256), platform.delta_table_dtype()),
+            s((256, 256), table),
             s((), jnp.float32), s((), jnp.float32),
             s((1, N), jnp.float32), s((1, N), jnp.float32),
             s((N,), jnp.float32), s((256,), jnp.float32),
             s((N,), jnp.float32), s((), jnp.float32))
-    compiled = jax.jit(qd).lower(*args).compile()
-    assert "tpu_custom_call" not in compiled.as_text()  # the XLA twin
+    hlo = jax.jit(qd).lower(*args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "onehot_qdot" in hlo
+    assert " while(" not in hlo
 
 
 @pytest.fixture(scope="module")
@@ -158,8 +162,58 @@ def test_serve_step_scopes_survive_tpu_fusion(one_chip, on_tpu,
            and m.group(2) in table}
     assert ops and all(table[n] != obs.UNSCOPED for n in ops), \
         [n for n in ops if table[n] == obs.UNSCOPED][:5]
+    qdots = {"qdot.wqkv", "qdot.wo", "qdot.w_gateup", "qdot.w_down"}
     kernel = [n for n, line in ops.items() if "tpu_custom_call" in line]
-    assert kernel and {table[n] for n in kernel} == {obs.ATTENTION}
+    # the decode-attention kernel and the qdots' one-hot kernels
+    assert kernel and {table[n] for n in kernel} == {obs.ATTENTION} | qdots
     assert {obs.EMBED, obs.LAYERS, obs.ATTENTION, obs.FINAL_NORM,
-            obs.UNEMBED, obs.SAMPLE, "qdot.wqkv", "qdot.wo",
-            "qdot.w_gateup", "qdot.w_down"} <= set(table.values())
+            obs.UNEMBED, obs.SAMPLE} | qdots <= set(table.values())
+
+
+# published widths by smoke width, for the qwen3-1.7b serving tree:
+# d_model (and heads x head_dim), q|k|v, d_ff, gate|up, vocab, head_dim
+WIDE = {64: CFG.d_model, 128: (CFG.n_heads + 2 * CFG.n_kv) * HD,
+        192: CFG.d_ff, 384: 2 * CFG.d_ff, 512: CFG.vocab, 16: HD}
+
+
+def test_serve_step_runs_one_kernel_per_qdot(one_chip, on_tpu,
+                                             smoke_serving_tree):
+    """The decode step at the benchmark cell's widths (qwen3-1.7b cut to
+    two layers, four slots of 1,040 cache rows) compiled for the
+    described v5e: each qdot.* scope holds one Pallas call per layer and
+    no loop, where the blocked-XLA twin's K-block scans put 10,907 of a
+    step's 11,282 device ops under qdot.*."""
+    import dataclasses
+    import re
+
+    from repro import obs
+    from repro.models import transformer as T
+    from repro.train import make_serve_step
+    smoke, qcfg, params, _ = smoke_serving_tree
+    assert smoke.n_layers == 2
+    cfg = dataclasses.replace(CFG, n_layers=smoke.n_layers)
+    wide = jax.tree.map(lambda a: _spec(
+        one_chip, tuple(WIDE.get(d, d) for d in a.shape), a.dtype), params)
+    assert wide["units"][0]["mlp"]["w_gateup"].q.shape == (
+        2, CFG.d_model, 2 * CFG.d_ff)
+    state = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                         jax.eval_shape(lambda: T.init_decode_state(
+                             cfg, 4, 1040, per_slot=True)))
+    hlo = jax.jit(make_serve_step(cfg, qcfg)).lower(
+        wide, state, _spec(one_chip, (4, 1), jnp.int32)).compile().as_text()
+    table = obs.scopes_of_hlo(hlo)
+    per_scope = {}
+    for line in hlo.splitlines():
+        m = obs._INSTR.match(line)
+        if m and m.group(2) in table:
+            op = re.search(r" ([a-z][a-z\-]*)\(", line[m.end():])
+            kind = ("kernel" if "tpu_custom_call" in line
+                    else op.group(1) if op else "")
+            per_scope.setdefault(table[m.group(2)], []).append(kind)
+    for q in ("qdot.wqkv", "qdot.wo", "qdot.w_gateup", "qdot.w_down"):
+        assert per_scope[q].count("kernel") == cfg.n_layers, q
+        # the kernel is the projection's only matmul: no loop, and no
+        # row selection or lookup left to XLA
+        assert not {"while", "dot", "convolution", "gather"} & set(
+            per_scope[q]), q
+    assert per_scope[obs.ATTENTION].count("kernel") == cfg.n_layers
