@@ -3,12 +3,14 @@ reference.
 
 Once the window has closed and the program's state is freed, a sample of
 the requests that produced tokens (drawn from the seed, the longest
-always in it) is run through the reference, each as its prompt followed
-by its served tokens.  At every served position the number read is the
-gap by which the served token's reference logit lies below the
-reference's best logit there; the run compares the widest such gap
-(``gap_max``) with the cell's limit (``limits/<workload>.json``).  Valid
-for greedy decoding, which every mix here uses.
+always in it) is run through the plain reference (the ``Model`` of the
+configuration's architecture module, ``arch.load``, built on
+``reference.py``'s shared parts), each as its prompt followed by its
+served tokens.  At every served position the number read is the gap by
+which the served token's reference logit lies below the reference's
+best logit there; the run compares the widest such gap (``gap_max``)
+with the cell's limit (``limits/<workload>.json``).  Valid for greedy
+decoding, which every mix here uses.
 
 The control (``control=True``, used by ``control.py`` and the tests,
 never by a benchmark run) reads, at the same positions, the gap of the
@@ -22,9 +24,9 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-import jax
 import numpy as np
 
+import arch
 import reference
 import serving
 import weights as weights_mod
@@ -73,14 +75,13 @@ def compare(cfg: dict, seed: int, requests: list, *, max_tokens: int = 512,
     if bad or not reqs:        # nothing sound to compare: fails any limit
         out["gap_max"] = 1e9
         return out
+    Model = arch.load(cfg).Model
     w = weights_mod.make(cfg, seed)
-    ref = reference.Model(cfg, w).calibrate(
+    ref = Model(cfg, w).calibrate(
         serving.calibration_prompts(cfg),
         cfg["serving"]["calibration"]["gen_tokens"])
-    ctl = (reference.Model(cfg, w, bits=4).with_ranges(ref.ranges)
-           if control else None)
-    for leaf in jax.tree.leaves(w["units"]):
-        leaf.delete()
+    ctl = Model(cfg, w, bits=4).with_ranges(ref.ranges) if control else None
+    del w           # the float leaves no model holds are freed here
     seqs = [np.concatenate([r.prompt, np.asarray(r.tokens[:-1], np.int32)])
             for r in reqs]
     served = [slice(len(r.prompt) - 1, len(r.prompt) - 1 + len(r.tokens))

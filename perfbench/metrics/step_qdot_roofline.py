@@ -8,13 +8,17 @@ device time is the union of the intervals of the ops the program puts
 under a qdot scope (repro.obs.scope_table: each instruction of the
 compiled step and its innermost jax.named_scope, qdot.<projection>), so a
 while loop and the ops of its body count once.  The least time is, per
-step, every layer's merged projections at M = slots, each an int8 (M, K)
-x (K, N) matmul with the qdot's interface (roofline.int8_matmul,
-roofline.least_time), as qdot_roofline counts its isolated calls.  No
-value where the program names no scopes.
+step, every layer's merged projections at M = slots (the architecture
+module's ``projections``), each an int8 (M, K) x (K, N) matmul with the
+qdot's interface (roofline.int8_matmul, roofline.least_time), as
+qdot_roofline counts its isolated calls.  No value where the program
+names no scopes.
 
 ``scope_ns`` is the reduction the scope metrics share (unembed_roofline).
 """
+import bisect
+
+import arch
 import roofline
 import tracefile
 
@@ -39,20 +43,17 @@ def scope_ns(ctx, keep):
         table = obs.scope_table(STEP)
     except (ImportError, LookupError):
         return None, 0
-    ops = [o for o in ctx.ops
-           if keep(table.get(o[0].partition(" = ")[0].lstrip("%"),
-                             obs.UNSCOPED))]
+    ops = sorted((o for o in ctx.ops
+                  if keep(table.get(o[0].partition(" = ")[0].lstrip("%"),
+                                    obs.UNSCOPED))), key=lambda o: o[1])
+    starts = [o[1] for o in ops]
+    longest = max((o[2] - o[1] for o in ops), default=0)
     ex = step_executions(ctx)
-    return sum(tracefile.busy_ns(ops, s, e) for s, e in ex), len(ex)
-
-
-def projections(c: dict) -> list:
-    """(K, N) of one layer's merged serving projections."""
-    d, f = c["hidden_size"], c["intermediate_size"]
-    h, kv, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    up = 2 * f if c["hidden_act"] == "silu" else f      # gate|up merged
-    return [(d, (h + 2 * kv) * hd), (h * hd, d), (d, up), (f, d)]
+    # only ops that start within ``longest`` before an execution can
+    # overlap it: one bisection each, not a walk over every op per step
+    return sum(tracefile.busy_ns(
+        ops[bisect.bisect_left(starts, s - longest):
+            bisect.bisect_left(starts, e)], s, e) for s, e in ex), len(ex)
 
 
 def read(ctx):
@@ -63,5 +64,5 @@ def read(ctx):
     c = ctx.cfg
     least = steps * c["num_hidden_layers"] * sum(
         roofline.least_time(*roofline.int8_matmul(M, K, N), ctx.pk)
-        for K, N in projections(c))
+        for _, _, K, N in arch.load(c).projections(c))
     return 100.0 * least / (ns / 1e9)

@@ -30,14 +30,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch
 import reference
 
 DESIGN = "design2"
 SX = 2.0 ** -5          # stated activation grid: x = (q - ZX) * SX
 ZX = 128
-
-# merged wrappers (fuse_projections) and their members, in column order
-GROUPS = {"wqkv": ("wq", "wk", "wv"), "w_gateup": ("w_gate", "w_up")}
 
 
 @dataclasses.dataclass
@@ -58,30 +56,22 @@ class Site:
 
 def operands(params: dict, cfg: dict, seed: int, M: int) -> list:
     """The check's operands from the run's float parameter tree (before
-    the program consumes it): one Site per projection of layer 0."""
+    the program consumes it): one Site per projection of layer 0, as the
+    architecture module lists them (``projections``)."""
     grid = reference.Grid(8)
     unit = params["units"][0]
     rng = np.random.default_rng([seed, 8])
-    names = {g: list(unit[g]) for g in ("attn", "mlp")}
     sites = []
-    for group in ("attn", "mlp"):
-        ws = [n for n in names[group] if n.startswith("w")]
-        merged = [(m, mem) for m, mem in GROUPS.items()
-                  if all(n in ws for n in mem)]
-        taken = {n for _, mem in merged for n in mem}
-        todo = merged + [(n, (n,)) for n in ws if n not in taken]
-        for name, mem in todo:
-            qs, ss, zs = [], [], []
-            for n in mem:
-                q, s, z = reference.quantize_weight(unit[group][n][0], grid)
-                qs.append(q)
-                ss.append(s)
-                zs.append(z)
-            K = int(qs[0].shape[0])
-            qx = rng.integers(0, 256, (M, K))
-            x = jnp.asarray((qx - ZX) * SX, jnp.float32)
-            sites.append(Site(name, tuple((group, n) for n in mem),
-                              qs, ss, zs, x))
+    for name, members, K, _ in arch.load(cfg).projections(cfg):
+        qs, ss, zs = [], [], []
+        for group, n in members:
+            q, s, z = reference.quantize_weight(unit[group][n][0], grid)
+            qs.append(q)
+            ss.append(s)
+            zs.append(z)
+        qx = rng.integers(0, 256, (M, K))
+        x = jnp.asarray((qx - ZX) * SX, jnp.float32)
+        sites.append(Site(name, members, qs, ss, zs, x))
     return sites
 
 
@@ -107,10 +97,10 @@ def program_calls(sites: list, qcfg) -> list:
     from repro.calib import attach_comp_cols
     from repro.quant import qdot
     from repro.quant.linear import QuantizedWeight, fuse_projections
-    tree = {"attn": {}, "mlp": {}}
+    tree = {}
     for s in sites:
         for (group, n), q, sw, zw in zip(s.members, s.q, s.scale, s.zp):
-            tree[group][n] = QuantizedWeight(
+            tree.setdefault(group, {})[n] = QuantizedWeight(
                 None, q, sw, zw,
                 colsum=q.sum(0, keepdims=True).astype(jnp.float32),
                 act_scale=jnp.float32(SX), act_zp=jnp.float32(ZX),

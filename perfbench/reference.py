@@ -1,11 +1,14 @@
 """Plain reference of a served configuration, for the check that decides
-``correct``.  It imports nothing of the program and takes nothing the
-program made: it builds its own weights from the seed (``weights.make``,
-the same call the harness hands the program), quantizes them, calibrates
-its own static activation scales, multiplies through its own model of
-the configuration's 8x8 multiplier (``PRODUCTS``: Design #2 gate by gate
+``correct``: the numerical parts every architecture shares.  It imports
+nothing of the program and takes nothing the program made: the harness
+builds its weights from the seed (``weights.make``, the same call it
+hands the program), and the architecture module's ``Model`` (a
+``Decoder``, in ``arch/``) quantizes them, calibrates its own
+static activation scales, multiplies through its own model of the
+configuration's 8x8 multiplier (``PRODUCTS``: Design #2 gate by gate
 from ``design2.py``, or the exact Dadda tree), and runs each sequence as
-one causal pass in float32 at HIGHEST matmul precision.
+one causal pass in float32 at HIGHEST matmul precision.  The module's
+docstring states its norms, rotary, MLP and head.
 
 Semantics written down here, as the configuration states them:
 
@@ -21,8 +24,8 @@ Semantics written down here, as the configuration states them:
   mean-field compensation sum_k mu_r[qx] + sum_k mu_c[qw] - K mu (the
   conditional means of P's error table), then the zero-point algebra
   - z_w rowsum(qx) - z_x colsum(qw) + K z_x z_w, times s_x s_w;
-* K and V pass through the cache's dtype (bfloat16) before attention;
-* the unembedding is the tied embedding in float32, exact.
+* K and V pass through the cache's dtype (bfloat16) before attention,
+  which is grouped-query softmax attention in float32.
 
 ``bits=4`` gives the control: the same pass with weights and
 activations on a 4-bit grid (levels 0..15, the same calibration ranges)
@@ -127,97 +130,52 @@ def _linear(x, wq, act, grid: Grid, comp, design: str):
     return (yint.astype(jnp.float32) - cterm) * (sx * sw)
 
 
-def rmsnorm(x, g, eps: float):
-    var = jnp.mean(jnp.square(x), -1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * g
+def _attention(q, k, v, mask):
+    """GQA attention of q (T, H, hd) over k, v (S, Kv, hd) where mask
+    (T, S) allows: each key-value head serves H / Kv query heads."""
+    T, H, hd = q.shape
+    kv = k.shape[1]
+    qg = q.reshape(T, kv, H // kv, hd)
+    lg = jnp.einsum("tngd,snd->ngts", qg, k,
+                    precision=HIGHEST) / math.sqrt(hd)
+    lg = jnp.where(mask[None, None], lg, -jnp.inf)
+    out = jnp.einsum("ngts,snd->tngd", jax.nn.softmax(lg, -1), v,
+                     precision=HIGHEST)
+    return out.reshape(T, H, hd)
 
 
-def rope(x, pos, theta: float):
-    """x: (T, H, D); pos: (T,) positions."""
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None, None] * freqs[None, None, :]
-    c, s = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
+def _through_cache(a):
+    """K or V as the cache holds it (bfloat16), back in float32."""
+    return a.astype(jnp.bfloat16).astype(jnp.float32)
 
 
-class Model:
-    """The reference forward of one configuration at one grid."""
+class Decoder:
+    """The reference forward of one configuration at one grid, as far as
+    every architecture shares it: the token-by-token calibration pass and
+    the one causal pass per sequence.  An architecture module's ``Model``
+    subclasses it and sets ``embed`` (the input table, (V, D)),
+    ``layers`` (per layer, what its ``_layer`` takes), ``kv`` and ``hd``
+    (key-value heads and their size), and defines
 
-    def __init__(self, cfg: dict, weights: dict, bits: int = 8):
+    * ``_layer(p, x, pos, attend, act_of)``: one layer over rows x (T, D)
+      at positions pos (T,); ``attend(q, k, v)`` is the attention over
+      the caller's keys (k and v pass through the cache's dtype there),
+      and ``act_of(site, v)`` the (scale, zero point) a projection input
+      is quantized with;
+    * ``_logits(x)``: the final norm and the head.
+    """
+
+    def __init__(self, cfg: dict, bits: int = 8):
         self.grid = Grid(bits)
-        self.h = cfg["num_attention_heads"]
-        self.kv = cfg["num_key_value_heads"]
-        self.hd = cfg["head_dim"]
-        self.eps = cfg["rms_norm_eps"]
-        self.theta = cfg["rope_theta"]
-        self.act_fn = cfg["hidden_act"]
-        self.qk_norm = cfg["qk_norm"]
         self.design = cfg["serving"]["design"]
         mu_r, mu_c, mu = error_tables(self.design)
         self.comp = (jnp.asarray(mu_r), jnp.asarray(mu_c), jnp.float32(mu))
-        self.embed = weights["embed"]
-        self.final_norm = weights["final_norm"]
-        unit = weights["units"][0]
-        self.layers = []
-        for l in range(cfg["num_hidden_layers"]):
-            lw = jax.tree.map(lambda a: a[l], unit)
-            prep = {}
-            for k, w in [*lw["attn"].items(), *lw["mlp"].items(),
-                         ("norm1", lw["norm1"]), ("norm2", lw["norm2"])]:
-                prep[k] = (quantize_weight(w, self.grid) if k.startswith("w")
-                           else w)
-            self.layers.append(prep)
         self.ranges = None        # per layer: {site: (lo, hi)}
 
-    def _layer(self, p, x, pos, attend, act_of):
-        """One layer over rows x (T, D) at positions pos (T,).
-        ``attend(q, k, v)`` is the attention over the caller's keys, and
-        ``act_of(site, v)`` the (scale, zero point) a projection input
-        is quantized with."""
-        def lin(site, w, v):
-            return _linear(v, p[w], act_of(site, v), self.grid, self.comp,
-                           self.design)
-        T = x.shape[0]
-        h = rmsnorm(x, p["norm1"], self.eps)
-        q = lin("qkv", "wq", h).reshape(T, self.h, self.hd)
-        k = lin("qkv", "wk", h).reshape(T, self.kv, self.hd)
-        v = lin("qkv", "wv", h).reshape(T, self.kv, self.hd)
-        if self.qk_norm:
-            q = rmsnorm(q, p["q_norm"], self.eps)
-            k = rmsnorm(k, p["k_norm"], self.eps)
-        q = rope(q, pos, self.theta)
-        k = rope(k, pos, self.theta)
-        k = k.astype(jnp.bfloat16).astype(jnp.float32)
-        v = v.astype(jnp.bfloat16).astype(jnp.float32)
-        o = attend(q, k, v)
-        x = x + lin("o", "wo", o.reshape(T, self.h * self.hd))
-        h2 = rmsnorm(x, p["norm2"], self.eps)
-        if self.act_fn == "silu":
-            hh = jax.nn.silu(lin("up", "w_gate", h2)) * lin("up", "w_up", h2)
-        elif self.act_fn == "relu2":
-            hh = jnp.square(jax.nn.relu(lin("up", "w_up", h2)))
-        else:
-            raise ValueError(f"unknown hidden_act {self.act_fn!r}")
-        return x + lin("down", "w_down", hh)
-
-    def _logits(self, x):
-        x = rmsnorm(x, self.final_norm, self.eps)
-        return jnp.matmul(x, self.embed.T, precision=HIGHEST)
-
-    def _attention(self, q, k, v, mask):
-        """GQA attention of q (T, H, hd) over k, v (S, Kv, hd) where
-        mask (T, S) allows."""
-        g = self.h // self.kv
-        T = q.shape[0]
-        qg = q.reshape(T, self.kv, g, self.hd)
-        lg = jnp.einsum("tngd,snd->ngts", qg, k,
-                        precision=HIGHEST) / math.sqrt(self.hd)
-        lg = jnp.where(mask[None, None], lg, -jnp.inf)
-        out = jnp.einsum("ngts,snd->tngd", jax.nn.softmax(lg, -1), v,
-                         precision=HIGHEST)
-        return out.reshape(T, self.h, self.hd)
+    def linear(self, wq, x, act):
+        """Quantized projection of x by a weight from quantize_weight,
+        with this model's grid and multiplier."""
+        return _linear(x, wq, act, self.grid, self.comp, self.design)
 
     def calibrate(self, prompts: np.ndarray, gen_len: int = 2):
         """Static activation ranges from a token-by-token decode of
@@ -245,12 +203,12 @@ class Model:
 
                 def attend(q, k, v, l=l):
                     kc, vc = caches[l]
-                    kc = kc.at[:, t].set(k)
-                    vc = vc.at[:, t].set(v)
+                    kc = kc.at[:, t].set(_through_cache(k))
+                    vc = vc.at[:, t].set(_through_cache(v))
                     caches[l] = (kc, vc)
                     mask = (jnp.arange(n) <= t)[None]
                     return jnp.concatenate(
-                        [self._attention(q[b:b + 1], kc[b], vc[b], mask)
+                        [_attention(q[b:b + 1], kc[b], vc[b], mask)
                          for b in range(B)], 0)
 
                 x = self._layer(p, x, jnp.full((B,), t), attend, act_of)
@@ -284,12 +242,14 @@ class Model:
         pos_, seg_ = jnp.asarray(pos), jnp.asarray(seg)
         mask = ((seg_[:, None] == seg_[None, :])
                 & (pos_[None, :] <= pos_[:, None]))
+
+        def attend(q, k, v):
+            return _attention(q, _through_cache(k), _through_cache(v), mask)
         x = jnp.take(self.embed, jnp.asarray(ids), axis=0)
         for l, p in enumerate(self.layers):
             acts = {s: self.grid.params(lo, hi)
                     for s, (lo, hi) in self.ranges[l].items()}
-            x = self._layer(p, x, pos_,
-                            lambda q, k, v: self._attention(q, k, v, mask),
+            x = self._layer(p, x, pos_, attend,
                             lambda site, v, acts=acts: acts[site])
         lg = self._logits(x)
         out, o = [], 0

@@ -8,6 +8,8 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+import arch
+
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
 
@@ -54,13 +56,9 @@ def decode_attention_bytes(positions, heads: int, kv_heads: int,
 
 
 def projection_macs(cfg: dict) -> int:
-    """Multiply-accumulates of one layer's projections for one token."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    attn = d * (h + 2 * kv) * hd + h * hd * d
-    mlp = (3 if cfg["hidden_act"] == "silu" else 2) * d * f
-    return attn + mlp
+    """Multiply-accumulates of one layer's projections for one token, as
+    the configuration's architecture module counts them."""
+    return arch.load(cfg).layer_macs(cfg)
 
 
 def decode_step_ops(cfg: dict, positions) -> int:

@@ -4,21 +4,24 @@
         --trace <0|1>
 
 The cell is looked up by name in BENCHMARK.json at the checkout's root:
-its configuration file (configs/), its traffic mix (mixes/<traffic>.json)
-and its metrics.  One process, on the chips it finds; it exits non-zero
-with no result line where JAX finds no TPU, a device kind missing from
-peaks.json, or fewer chips than the cell asks for.
+its configuration file (configs/), the architecture module the file
+names (arch/, through ``arch.load``), its traffic mix
+(mixes/<traffic>.json) and its metrics.  One process, on the chips it
+finds; it exits non-zero with no result line where JAX finds no TPU, a
+device kind missing from peaks.json, or fewer chips than the cell asks
+for.
 
-A run: set-up (weights from the seed, the program's prepare_params with
-calibration, compiles or compile-cache loads, the slot fill and one warm
-step), then the closed loop for --seconds, then the checks against the
-plain reference: the served tokens (check.py) and the program's Design #2
-qdot at the decode shapes (qdot_check.py).  With --trace 1 the
-window and isolated calls of each projection are traced, and the line
-carries the per-layer metrics (metrics/<name>.py) in place of the
-end-to-end ones.  The last line of standard output is one JSON object;
-the numbers compared, with their limits, are the last lines of standard
-error and the last key of that object.
+A run: set-up (weights from the seed by the architecture module's shapes
+and leaf rule, the program's prepare_params with calibration, compiles
+or compile-cache loads, the slot fill and one warm step), then the
+closed loop for --seconds, then the checks against the plain reference:
+the served tokens (check.py, through the architecture module's Model)
+and the program's Design #2 qdot at the decode shapes (qdot_check.py).
+With --trace 1 the window and isolated calls of each projection are
+traced, and the line carries the per-layer metrics (metrics/<name>.py)
+in place of the end-to-end ones.  The last line of standard output is
+one JSON object; the numbers compared, with their limits, are the last
+lines of standard error and the last key of that object.
 
 ``--fault <kind>`` plants one of faults.py's faults in the timed path, to
 show ``correct`` coming out false at the cell's own size; the benchmark's
@@ -45,6 +48,7 @@ for p in (str(ROOT / "src"), str(HERE)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+import arch  # noqa: E402
 import faults  # noqa: E402
 
 # host spans the trace reduction attributes device time and idle gaps to
@@ -68,7 +72,9 @@ def parse(argv=None) -> argparse.Namespace:
 
 
 def load_cell(workload: str, bench: Path = ROOT / "BENCHMARK.json") -> dict:
-    """Everything BENCHMARK.json and the cell's files say about a cell."""
+    """Everything BENCHMARK.json and the cell's files say about a cell.
+    Stops, before any device work, where the configuration's
+    architecture module (arch/) is missing."""
     spec = json.loads(bench.read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
@@ -77,6 +83,10 @@ def load_cell(workload: str, bench: Path = ROOT / "BENCHMARK.json") -> dict:
     w = cells[workload]
     conf = {c["name"]: c for c in spec["configs"]}[w["config"]]
     cfg = json.loads((ROOT / conf["file"]).read_text())
+    try:
+        arch.load(cfg)
+    except LookupError as e:
+        raise SystemExit(f"{conf['file']}: {e}") from None
     mix = json.loads((HERE / "mixes" / f"{w['traffic']}.json").read_text())
 
     def applies(m):

@@ -1,7 +1,9 @@
 """Drives the system under test: set-up through the program's own serving
 entry points, then the closed loop of one cell.
 
-Set-up: weights from the seed (``weights.make``), ``serve.prepare_params``
+Set-up: the program's ArchConfig (the architecture module's
+``program_config``), weights from the seed (``weights.make``),
+``serve.prepare_params``
 (static scales from calibration, the fused qdot backend, merged
 projections), the per-slot decode state, AOT compiles of the decode step
 and of the B = 1 prefill at every prompt length the run's requests have,
@@ -27,26 +29,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch as arch_mod
 import faults
 import qdot_check
 import traffic
 import weights as weights_mod
 
 SPAN = jax.profiler.TraceAnnotation
-
-
-def arch_config(cfg: dict):
-    """The program's ArchConfig for a configuration file."""
-    from repro.models.transformer import ArchConfig
-    mlp = {"silu": "swiglu", "relu2": "relu2"}[cfg["hidden_act"]]
-    return ArchConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab=cfg["vocab_size"], head_dim=cfg["head_dim"], mlp_kind=mlp,
-        qk_norm=cfg["qk_norm"], rope_theta=float(cfg["rope_theta"]),
-        max_seq=cfg["max_position_embeddings"])
 
 
 def serve_argv(cfg: dict) -> list:
@@ -112,7 +101,7 @@ class Server:
         from repro.launch import serve
         self.serve = serve
         self.cfg, self.mix, self.seed, self.fault = cfg, mix, seed, fault
-        self.arch = arch_config(cfg)
+        self.arch = arch_mod.load(cfg).program_config(cfg)
         self.args = serve.parse_args(serve_argv(cfg))
         self.qcfg = serve.quant_config(self.args)
         self.slots = mix["streams"]

@@ -25,7 +25,7 @@ def test_int8_matmul_bound():
 
 
 def test_decode_step_ops_count_every_matmul():
-    cfg = {"hidden_size": 8, "intermediate_size": 16,
+    cfg = {"model_type": "qwen3", "hidden_size": 8, "intermediate_size": 16,
            "num_attention_heads": 2, "num_key_value_heads": 1,
            "head_dim": 4, "hidden_act": "silu", "num_hidden_layers": 3,
            "vocab_size": 10}

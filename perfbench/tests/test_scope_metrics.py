@@ -15,7 +15,7 @@ from repro import obs
 DATA = Path(__file__).resolve().parent / "data"
 PK = {"bf16_flops_per_s": 197e12, "int8_ops_per_s": 393e12,
       "hbm_bytes_per_s": 819e9}
-CFG = {"hidden_size": 64, "intermediate_size": 192,
+CFG = {"model_type": "qwen3", "hidden_size": 64, "intermediate_size": 192,
        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
        "num_hidden_layers": 2, "vocab_size": 512, "hidden_act": "silu"}
 
