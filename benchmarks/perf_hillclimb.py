@@ -11,7 +11,7 @@ Cells (selection rationale in EXPERIMENTS.md):
 
 Also hosts the delta-kernel block-shape autotuner (``--autotune-delta``):
 sweeps (TM, TN, TK) for kernels.approx_matmul.delta_matmul and times
-the fused serving qdot (ops.fused_qdot, per quant mode: the one-hot
+the fused serving qdot (ops.fused_qdot, per quant mode: the onehot_qdot
 kernel and the twin's k_block) on a fixed matmul shape, recording to
 experiments/delta_autotune.json; and the serving-step tuner
 (``--autotune-serve``): the fused qdot at the PREFILL shape
@@ -124,7 +124,7 @@ def autotune_delta(shape=(256, 256, 256), design: str = "design2",
 
 def autotune_fused(shape=(256, 256, 256), design: str = "design2",
                    out: str = "experiments/delta_autotune.json"):
-    """Time the fused serving qdot's one-hot kernel (its blocks come
+    """Time the fused serving qdot's onehot_qdot kernel (its blocks come
     from the shape) per quant mode (asym_u8 / sym_i8) and learn the XLA
     twin's k_block, recording the results to ``out``.  Off-TPU the
     kernel runs in interpret mode: re-run on hardware for real
@@ -170,11 +170,11 @@ def autotune_fused(shape=(256, 256, 256), design: str = "design2",
                 x, qw, dlut, sx=sx, zx=zx, sw=sw, zw=zw, colsum=colsum,
                 signed=signed, lowering=lowering, **kw))
 
-        # the one-hot kernel takes its blocks from the shape: one point
+        # onehot_qdot takes its blocks from the shape: one point
         f = fused("pallas")
         us = bench_us(lambda: f(x, qw), reps=3)
         pallas_results = [{"us_per_call": round(us, 1)}]
-        print(f"  fused[{mode}] pallas (one-hot): {us:.0f} us")
+        print(f"  fused[{mode}] pallas (onehot_qdot): {us:.0f} us")
         kbs = [kb for kb in DELTA_REF_KB_CANDIDATES if K % kb == 0] \
             or [next(kb for kb in (32, 16, 8, 4, 2, 1) if K % kb == 0)]
         xla_results = []

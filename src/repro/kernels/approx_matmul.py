@@ -6,11 +6,12 @@ Four kernels:
     (kernels.ops.fused_qdot): float activations in, float32 out, one
     pallas_call.  It quantizes the activations with the static scale,
     selects their rows of the approximate product table P = a*b + D (D
-    the delta table, or the selected table of a bank) split into two
-    bfloat16 byte planes, contracts those rows on the MXU with a one-hot
-    of the weights that it builds in VMEM from an iota and a compare,
-    and dequantizes the tile before it leaves VMEM.  No gather anywhere,
-    bit-exact, and the same cost for every table.
+    the delta table, or the selected table of a bank) with a one-hot
+    contraction on the MXU against P's two bfloat16 byte planes, joins
+    the planes into int32 rows, looks each weight up in its row with the
+    TPU's lane gather (two 128-lane halves, one select), and dequantizes
+    the tile before it leaves VMEM.  Bit-exact, and the same cost for
+    every table.
 
   * ``delta_matmul``   — the two-stage integer path (bit-exact, the
     ``pallas`` backend).  Mirrors the paper's two-stage reduction at the
@@ -39,9 +40,10 @@ Four kernels:
 
 The M/N grid axes are marked ``parallel`` (K stays ``arbitrary`` — the
 output tile is revisited as accumulator).  The last three gather per
-element and do not build for the TPU (kernels.platform says why, and
-raises if one is requested there); on the CPU every kernel runs in
-interpret mode, where the tests check them against kernels/ref.py.
+element from whole tables and do not build for the TPU (kernels.platform
+says why, and raises if one is requested there); on the CPU every kernel
+runs in interpret mode, where the tests check them against
+kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -167,7 +169,7 @@ def delta_matmul(a: jax.Array, b: jax.Array, dlut: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Kernel A': the fused qdot, its lookup a one-hot contraction on the MXU
+# Kernel A': the fused qdot: rows selected on the MXU, looked up by lane gather
 # ---------------------------------------------------------------------------
 
 def product_planes(dlut, layer=None, offset: int = 0):
@@ -195,10 +197,18 @@ def product_planes(dlut, layer=None, offset: int = 0):
     return xp.concatenate([p >> 8, p & 255], axis=1).astype(xp.float32), c
 
 
-_KS = 16    # weight rows per one-hot chunk: one (16, 128) bfloat16 tile
+_KS = 8     # weight rows per gather group: one (8, 128) int32 tile
+_KO = 16    # weight rows per one-hot chunk: one (16, 128) bfloat16 tile
+_MC = 4     # activation rows whose partial sums one pass keeps in vregs
 _TM = 32    # most activation rows per tile (the row selection unrolls them)
 _TN = 512   # most weight columns per tile
 _KB = 512   # most weight rows per grid step
+# Most activation rows per tile that take the lane gather; taller tiles
+# take the one-hot contraction.  The gather costs 2*tm lane permutes per
+# 1,024 weight elements, the one-hot about 1,000 VPU ops whatever tm is.
+# TPU v5e, w_gate|up (K 2048, N 12288), Design #2, isolated calls, ms:
+# M 4: gather 1.28, one-hot 2.75; M 16: 5.05, 4.47; M 32: 10.02, 6.52.
+_TG = 12
 
 
 def _largest_divisor(total: int, step: int, most: int) -> int:
@@ -208,20 +218,28 @@ def _largest_divisor(total: int, step: int, most: int) -> int:
                if total % d == 0)
 
 
+def contraction(M: int, K: int, N: int) -> str:
+    """The weight-side contraction ``onehot_qdot`` runs at this shape:
+    'gather' (lane gathers from the selected rows) or 'onehot' (a
+    one-hot of the weights on the MXU), chosen by the tile's rows."""
+    return "gather" if _onehot_blocks(M, K, N)[0] <= _TG else "onehot"
+
+
 def _onehot_blocks(M: int, K: int, N: int):
     """Block sizes from the shapes: M rows per tile (all of M up to 32),
     N columns per tile (up to 512), and K rows per grid step (a multiple
     of 128 up to 512), each dividing the padded extent where it can so
     that no operand is padded at the serving shapes.  Every buffer fits
-    the default scoped VMEM: a block's selected rows (2*tm*kb*256
-    bfloat16) at most 4 MiB, the accumulator over all of N (2*tm*N f32)
-    at most 4 MiB where tm can shrink to 8."""
+    the default scoped VMEM: a block's selected rows (tm*kb*256 int32,
+    or 2*tm*kb*256 bfloat16) at most 2 MiB, the accumulator over all of
+    N (tm*N int32, or 2*tm*N f32) at most 4 MiB where tm can shrink to
+    8."""
     Np = _ceil_mul(N, 128)
     tm = M if M <= _TM else _TM
     while tm > 8 and 2 * tm * Np * 4 > (4 << 20):
         tm = max(8, tm // 2 // 8 * 8)
     tn = _largest_divisor(Np, 128, _TN)
-    most = max(128, (2 << 20) // (2 * tm * 256 * 2) // 128 * 128)
+    most = max(128, (2 << 20) // (tm * 256 * 4) // 128 * 128)
     kb = _largest_divisor(_ceil_mul(K, 128), 128, min(_KB, most))
     return tm, tn, kb
 
@@ -237,25 +255,42 @@ def _onehot_kernel(*refs, scalars, cols, offset: int, K: int, asym: bool,
     parameters of sw, zw (f32).
     Tensor operands:
       x_ref   (tm, kb) f32 activations, quantized here.
-      qw_ref  (kb/16, 16, tn) int32 weights.
+      qw_ref  (kb/ks, ks, tn) int32 weights, ks = 8 (gather) or 16
+              (one-hot).
       p_ref   (256, 512) bf16 product planes (product_planes).
       cr_ref  (1, 256) f32 row compensation table (with compensation).
       then one (1, tn) f32 row per per-column epilogue parameter, named
       by ``cols`` (of sw, zw, colsum, comp_col).
     Output: (tm, tn) f32; its index map holds the first N tile until the
     last K block, so only finished tiles are written back.
-    Scratch: acc (N tiles, 2*tm, tn) f32, the hi and lo planes' sums;
-    r (2*tm, kb, 256) bf16, the planes' rows selected by the block's
-    activations; the rowsum of qx (tm, 1) f32 (asym); the histogram of
-    qx (tm, 256) f32 (with compensation).
+    Scratch: acc, the sums over K: (N tiles, tm, tn) int32 of P - c
+    (gather), or (N tiles, 2*tm, tn) f32 of the hi and lo planes
+    (one-hot); r, the rows the block's activations select: (tm, kb, 256)
+    int32 of P - c, or (2*tm, kb, 256) bf16 of both planes; the rowsum
+    of qx (tm, 1) f32 (asym); the histogram of qx (tm, 256) f32 (with
+    compensation).
 
     At each K block's first N tile the activations select their rows of
     both planes on the MXU (a one-hot of qx against the planes, exact:
-    one nonzero term).  Per weight row k the one-hot onehot[u, n] =
-    (qw[k, n] + off == u) is built on the VPU from an iota and a
-    compare, and only in VMEM; the MXU contracts it with the selected
-    rows, so no lookup is a gather.  The quantizer and the epilogue are
-    kernels.ref.fused_qdot_ref's op for op."""
+    one nonzero term).  The weight side is a lookup within each row,
+    out[m, n] += P[qx[m, k] + off, qw[k, n] + off] - c, done one of two
+    ways (``contraction``), the same integers either way:
+
+      gather  the planes are joined into int32 rows R[m, k, :].  Per
+              (8, 128) tile of weights the kernel gathers both 128-lane
+              halves of the 8 rows' R along lanes with the index
+              (qw + off) & 127 (the TPU's lane permute) and keeps the
+              half that (qw + off) >= 128 names; the index and the half
+              serve every activation row.  Partial sums stay in vregs,
+              8 sublanes each, and are reduced into the accumulator once
+              per K block and N tile.
+      one-hot per weight row k the one-hot onehot[u, n] = (qw[k, n] +
+              off == u) is built on the VPU from an iota and a compare,
+              only in VMEM, and the MXU contracts it with both planes'
+              rows (exact in f32: every sum is an integer below 2**24).
+
+    The quantizer and the epilogue are kernels.ref.fused_qdot_ref's op
+    for op."""
     ns, nc = len(scalars), len(cols)
     sc = dict(zip(scalars, refs[:ns]))
     x_ref, qw_ref, p_ref = refs[ns:ns + 3]
@@ -272,10 +307,12 @@ def _onehot_kernel(*refs, scalars, cols, offset: int, K: int, asym: bool,
 
     k, j = pl.program_id(1), pl.program_id(2)
     tm, kb = x_ref.shape
+    ns = qw_ref.shape[-1] // 128                # 128-lane slices of a tile
+    gather = tm <= _TG
 
     @pl.when(k == 0)
     def _init():
-        acc_ref[j] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+        acc_ref[j] = jnp.zeros(acc_ref.shape[1:], acc_ref.dtype)
 
         @pl.when(j == 0)
         def _init_rows():
@@ -307,31 +344,71 @@ def _onehot_kernel(*refs, scalars, cols, offset: int, K: int, asym: bool,
             oh = (qt[:, m:m + 1] == lanes).astype(jnp.bfloat16)
             rows = jax.lax.dot(oh, planes,
                                preferred_element_type=jnp.float32)
-            r_ref[m] = rows[:, :256].astype(jnp.bfloat16)
-            r_ref[tm + m] = rows[:, 256:].astype(jnp.bfloat16)
+            if gather:
+                r_ref[m] = (rows[:, :256].astype(jnp.int32) * 256
+                            + rows[:, 256:].astype(jnp.int32))
+            else:
+                r_ref[m] = rows[:, :256].astype(jnp.bfloat16)
+                r_ref[tm + m] = rows[:, 256:].astype(jnp.bfloat16)
             if compensate:
                 hist_ref[m:m + 1, :] += jnp.sum(oh.astype(jnp.float32),
                                                 axis=0, keepdims=True)
 
-    u = jax.lax.broadcasted_iota(jnp.int32, (256, qw_ref.shape[-1]), 0)
-    u = u - offset
+    def lookup(m0, mc):
+        """Sum R[m, k, qw[k, n] + off] over the block's k for the rows
+        m0 .. m0 + mc, into the accumulator."""
+        def group(g, part):
+            w = qw_ref[g]                                   # (8, tn)
+            if offset:
+                w = w + offset
+            idx, upper = w & 127, w >= 128
+            k8 = pl.ds(pl.multiple_of(g * _KS, _KS), _KS)
+            part = list(part)
+            for m in range(mc):
+                lo, hi = r_ref[m0 + m, k8, :128], r_ref[m0 + m, k8, 128:]
+                for s in range(ns):
+                    ix = idx[:, s * 128:(s + 1) * 128]
+                    got = jnp.where(
+                        upper[:, s * 128:(s + 1) * 128],
+                        jnp.take_along_axis(hi, ix, axis=1,
+                                            mode="promise_in_bounds"),
+                        jnp.take_along_axis(lo, ix, axis=1,
+                                            mode="promise_in_bounds"))
+                    part[m * ns + s] += got
+            return tuple(part)
 
-    def chunk(c, acc):
-        w = qw_ref[c]                                       # (16, tn)
-        rc = r_ref[:, pl.ds(pl.multiple_of(c * _KS, _KS), _KS), :]
-        for jj in range(_KS):
-            onehot = (w[jj:jj + 1, :] == u).astype(jnp.bfloat16)
-            acc = acc + jax.lax.dot(rc[:, jj, :], onehot,
-                                    preferred_element_type=jnp.float32)
-        return acc
+        zero = jnp.zeros((_KS, 128), jnp.int32)
+        part = jax.lax.fori_loop(0, kb // _KS, group, (zero,) * (mc * ns))
+        for m in range(mc):
+            for s in range(ns):
+                acc_ref[j, pl.ds(m0 + m, 1), pl.ds(s * 128, 128)] += \
+                    jnp.sum(part[m * ns + s], axis=0, keepdims=True)
 
-    acc_ref[j] = jax.lax.fori_loop(0, kb // _KS, chunk, acc_ref[j])
+    if gather:
+        for m0 in range(0, tm, _MC):
+            lookup(m0, min(_MC, tm - m0))
+    else:
+        u = jax.lax.broadcasted_iota(jnp.int32, (256, ns * 128), 0) - offset
+
+        def chunk(c, acc):
+            w = qw_ref[c]                                   # (16, tn)
+            rc = r_ref[:, pl.ds(pl.multiple_of(c * _KO, _KO), _KO), :]
+            for jj in range(_KO):
+                onehot = (w[jj:jj + 1, :] == u).astype(jnp.bfloat16)
+                acc = acc + jax.lax.dot(rc[:, jj, :], onehot,
+                                        preferred_element_type=jnp.float32)
+            return acc
+
+        acc_ref[j] = jax.lax.fori_loop(0, kb // _KO, chunk, acc_ref[j])
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _epilogue():
         acc = acc_ref[j]
-        prod = (acc[:tm].astype(jnp.int32) * 256
-                + acc[tm:].astype(jnp.int32) + sc["kc"][0])
+        if gather:
+            prod = acc + sc["kc"][0]
+        else:
+            prod = (acc[:tm].astype(jnp.int32) * 256
+                    + acc[tm:].astype(jnp.int32) + sc["kc"][0])
         accf = prod.astype(jnp.float32)
         if compensate:
             rowc = jnp.sum(hist_ref[...] * cr_ref[...], axis=1,
@@ -348,8 +425,8 @@ def _onehot_kernel(*refs, scalars, cols, offset: int, K: int, asym: bool,
 def onehot_qdot(x: jax.Array, qw: jax.Array, planes, scalars: dict,
                 cols: dict, comp_r=None, *, offset: int = 0,
                 asym: bool = True, compensate: bool = False) -> jax.Array:
-    """The fused serving qdot as one Pallas call with no gather: float
-    activations x (M, K), int32 weights qw (K, N) -> float32 (M, N).
+    """The fused serving qdot as one Pallas call: float activations x
+    (M, K), int32 weights qw (K, N) -> float32 (M, N).
 
     planes: product_planes' (256, 512) table (exact in bfloat16).
     scalars: name -> (1,) array: "kc" int32 (K*c), "sx", and as the mode
@@ -358,26 +435,30 @@ def onehot_qdot(x: jax.Array, qw: jax.Array, planes, scalars: dict,
     parameters (of "sw", "zw", "colsum", "comp_col").  comp_r: the
     (256,) row compensation table, with ``compensate``.
 
-    The kernel quantizes x with the static scale, sums P[qx + off, qw +
-    off] over K as a one-hot contraction on the MXU (exact: every
-    plane's sum is an integer below 2**24 for K < 65,536) and applies
-    the dequant epilogue before the tile leaves VMEM.  Its cost is the
-    same whatever the table holds, and at the serving shapes no operand
-    is padded or prepared in XLA: the call is the projection's only
-    device op.
+    The kernel quantizes x with the static scale, selects the
+    activations' rows of P - c with a one-hot contraction on the MXU,
+    sums P[qx + off, qw + off] - c over K from those rows (by lane
+    gather or by a one-hot of the weights, as ``contraction`` says;
+    exact while K * max|P - c| < 2**31: K < 65,536 for every registered
+    design) and applies the dequant epilogue before the tile leaves
+    VMEM.  Its cost is the same whatever the table
+    holds, and at the serving shapes no operand is padded or prepared
+    in XLA: the call is the projection's only device op.
     """
     M, K = x.shape
     N = qw.shape[1]
     tm, tn, kb = _onehot_blocks(M, K, N)
+    gather = tm <= _TG
+    ks = _KS if gather else _KO
     Mp, Kp, Np = _ceil_mul(M, tm), _ceil_mul(K, kb), _ceil_mul(N, tn)
     nk = Kp // kb
     x = _pad_to(x.astype(jnp.float32), Mp, Kp)
-    qw = _pad_to(qw.astype(jnp.int32), Kp, Np).reshape(Kp // _KS, _KS, Np)
+    qw = _pad_to(qw.astype(jnp.int32), Kp, Np).reshape(Kp // ks, ks, Np)
     cols = {n: _pad_to(v, 1, Np) for n, v in cols.items()}
     snames, cnames = tuple(scalars), tuple(cols)
     in_specs = [
         pl.BlockSpec((tm, kb), lambda i, k, j, *_: (i, k)),
-        pl.BlockSpec((kb // _KS, _KS, tn), lambda i, k, j, *_: (k, 0, j)),
+        pl.BlockSpec((kb // ks, ks, tn), lambda i, k, j, *_: (k, 0, j)),
         pl.BlockSpec((256, 512), lambda i, k, j, *_: (0, 0)),
     ]
     operands = [x, qw, jnp.asarray(planes, jnp.bfloat16)]
@@ -387,8 +468,10 @@ def onehot_qdot(x: jax.Array, qw: jax.Array, planes, scalars: dict,
     in_specs += [pl.BlockSpec((1, tn), lambda i, k, j, *_: (0, j))
                  for _ in cnames]
     operands += [cols[n] for n in cnames]
-    scratch = [pltpu.VMEM((Np // tn, 2 * tm, tn), jnp.float32),
-               pltpu.VMEM((2 * tm, kb, 256), jnp.bfloat16)]
+    scratch = ([pltpu.VMEM((Np // tn, tm, tn), jnp.int32),
+                pltpu.VMEM((tm, kb, 256), jnp.int32)] if gather else
+               [pltpu.VMEM((Np // tn, 2 * tm, tn), jnp.float32),
+                pltpu.VMEM((2 * tm, kb, 256), jnp.bfloat16)])
     if asym:
         scratch.append(pltpu.VMEM((tm, 1), jnp.float32))
     if compensate:

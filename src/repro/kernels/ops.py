@@ -9,8 +9,8 @@
                shape; the signed offset folds into the gather index (no
                operand pre-shift).
   'fused'    — the fused quantize->product->dequant serving path
-               (``fused_qdot`` below; one one-hot kernel call on the
-               TPU).  quant.linear dispatches to it when a
+               (``fused_qdot`` below; one ``onehot_qdot`` kernel call
+               on the TPU).  quant.linear dispatches to it when a
                QuantizedWeight carries calibrated static activation
                scales; integer-operand approx_matmul calls with
                backend='fused' fall back to 'delta' (same integer core,
@@ -49,7 +49,7 @@ from repro import obs
 
 from . import platform, ref
 from .approx_matmul import delta_matmul, lut_matmul, residual_matmul
-from .approx_matmul import onehot_qdot, product_planes
+from .approx_matmul import contraction, onehot_qdot, product_planes
 
 _LUT_CACHE: dict = {}
 
@@ -267,7 +267,7 @@ def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array, *,
     dlut: (256, 256) delta table, or a stacked (L, 256, 256) BANK with
     ``dlut_idx`` a scalar int32 layer index (the mixed-design plan
     path: quant.linear.register_dlut_bank keeps the bank out of the
-    layer scan; the index selects the table before the one-hot kernel
+    layer scan; the index selects the table before the Pallas kernel
     splits it into planes, and folds into the gather base on the XLA
     twin).  sx/zx: calibrated static activation scale / zero point (zx
     None for sym_i8).  sw/zw: weight scale / zero point — scalar
@@ -275,14 +275,16 @@ def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array, *,
     the asym_u8 zero-point cross term.  comp_*: mean-field compensation
     tables (row table (256,), precomputed column colsum (N,), scalar
     mean) when ``compensate``.  ``lowering`` (kernels.platform): 'auto'
-    (the one-hot contraction on the TPU, the blocked-XLA twin
-    elsewhere), 'pallas' (the one-hot kernel, in interpret mode on the
+    (the Pallas kernel ``onehot_qdot`` on the TPU, the blocked-XLA
+    twin elsewhere), 'pallas' (that kernel, in interpret mode on the
     CPU) or 'xla' (the twin, ``k_block`` K rows per gather step).  The
     kernel applies the twin's quantizer and epilogue
     (ref.fused_qdot_ref) op for op; the integer product is bit-exact in both,
     and with compensation the row table's sum (a histogram of qx against
     the table in the kernel) differs by float reassociation only.  Each call traced under a
-    step adds one to obs.QDOT_LOWERING_ONEHOT or obs.QDOT_LOWERING_XLA.
+    step adds one to obs.QDOT_LOWERING_ONEHOT or obs.QDOT_LOWERING_XLA,
+    and a kernel call one to obs.QDOT_CONTRACTION_GATHER or
+    obs.QDOT_CONTRACTION_ONEHOT, by ``approx_matmul.contraction``.
     """
     lead = x.shape[:-1]
     K = x.shape[-1]
@@ -295,6 +297,10 @@ def fused_qdot(x: jax.Array, qw: jax.Array, dlut: jax.Array, *,
     if isinstance(x, jax.core.Tracer):
         obs.count(obs.QDOT_LOWERING_ONEHOT if onehot
                   else obs.QDOT_LOWERING_XLA)
+        if onehot:
+            obs.count(obs.QDOT_CONTRACTION_GATHER
+                      if contraction(x2.shape[0], K, N) == "gather"
+                      else obs.QDOT_CONTRACTION_ONEHOT)
     if onehot:
         planes, c = product_planes(dlut, layer, off)
         scalars = {"kc": jnp.asarray(K * c, jnp.int32).reshape(1)}

@@ -17,20 +17,21 @@ nowhere else:
   * where the persistent compile cache lives (``enable_compile_cache``,
     called by the entry points only).
 
-On the TPU the approximate qdot runs the one-hot contraction kernel
-(``approx_matmul.onehot_qdot``): the product table's rows, selected by
-the activations, meet a one-hot of the weights on the MXU, built in VMEM
-from an iota and a compare, so no gather is lowered at all.  The older
-kernels ``delta_matmul``, ``lut_matmul`` and ``residual_matmul`` gather
-per element, which Mosaic (the TPU Pallas compiler) does not lower: it
-has no lowering for the ``dynamic_slice`` that walks their K-subtiles,
-supports only 2-D gathers whose source fits one vreg along the gather
-axis, and cannot gather from a flat 65,536-entry table.  Off the TPU the
-qdot runs its blocked-XLA twin (``ref.fused_qdot_ref``), the one-hot
-kernel's oracle; the integer ``delta`` backend runs
-``ref.delta_matmul_ref`` everywhere.  The decode-attention kernel is
-made of 2-D dots and elementwise math, which Mosaic lowers, so the TPU
-runs it as a Pallas kernel.
+On the TPU the approximate qdot runs ``approx_matmul.onehot_qdot``: the
+activations select their rows of the product table with a one-hot
+contraction on the MXU, and each weight is looked up in its row with a
+lane gather (``jnp.take_along_axis`` along the lanes of an (8, 128)
+int32 tile, which Mosaic, the TPU Pallas compiler, lowers to the vreg's
+lane permute).  That is the one gather Mosaic lowers: 2-D, within one
+vreg along the gather axis.  The older kernels ``delta_matmul``,
+``lut_matmul`` and ``residual_matmul`` gather per element from a whole
+table, which it does not: it has no lowering for the ``dynamic_slice``
+that walks their K-subtiles, and cannot gather from a flat
+65,536-entry table.  Off the TPU the qdot runs its blocked-XLA twin
+(``ref.fused_qdot_ref``), the kernel's oracle; the integer ``delta``
+backend runs ``ref.delta_matmul_ref`` everywhere.  The decode-attention
+kernel is made of 2-D dots and elementwise math, which Mosaic lowers,
+so the TPU runs it as a Pallas kernel.
 """
 from __future__ import annotations
 
@@ -47,7 +48,7 @@ _REFUSED_ON_TPU = {
     "delta_matmul": "the stage-2 delta gather does not lower in Mosaic: no "
                     "dynamic_slice lowering for the K-subtile walk, and no "
                     "gather from the flat 65,536-entry table (the qdot's "
-                    "one-hot kernel, onehot_qdot, needs no gather)",
+                    "kernel, onehot_qdot, gathers within one vreg only)",
     "lut_matmul": "the per-k product-LUT gather does not lower in Mosaic "
                   "(only 2-D gathers within one vreg are supported)",
     "residual_matmul": "the factor-table jnp.take fails Mosaic's gather "
@@ -107,7 +108,7 @@ def delta_table_dtype():
     """Storage dtype of installed delta tables (calib.plan banks): the
     XLA twins gather from an int32 view, so the tables are pre-widened
     to int32 where the qdot runs the twin (a traced int16 table would
-    cost a 64Ki-element convert per layer per step); the one-hot kernel
+    cost a 64Ki-element convert per layer per step); the qdot kernel
     widens the one table it uses into its product planes, so there the
     tables keep their built form (None, int16)."""
     return jnp.int32 if lowering("qdot") == "xla" else None

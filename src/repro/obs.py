@@ -60,9 +60,13 @@ DECODE = "decode"
 TRACES_SERVE_STEP = "traces.serve_step"
 TRACES_PREFILL_STEP = "traces.prefill_step"
 # counters: qdot call sites traced, by the lowering kernels.ops.fused_qdot
-# chose (the one-hot contraction kernel or the blocked-XLA twin)
+# chose (the Pallas kernel onehot_qdot or the blocked-XLA twin), and of
+# the kernel's, by its weight-side contraction at the site's shape (lane
+# gathers, or a one-hot of the weights on the MXU)
 QDOT_LOWERING_ONEHOT = "qdot.lowering.onehot"
 QDOT_LOWERING_XLA = "qdot.lowering.xla"
+QDOT_CONTRACTION_GATHER = "qdot.contraction.gather"
+QDOT_CONTRACTION_ONEHOT = "qdot.contraction.onehot"
 
 MAX_SPANS = 4096
 

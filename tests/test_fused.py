@@ -23,6 +23,7 @@ from repro.core import lut as lutmod
 from repro.core.multipliers import MULTIPLIERS
 from repro.kernels import ops, ref
 from repro.kernels import platform
+from repro.kernels import approx_matmul
 from repro.kernels.approx_matmul import delta_matmul
 from repro.quant import QuantConfig, prequantize_weights, qdot
 from repro.quant import linear as qlin
@@ -79,7 +80,7 @@ def test_fused_bank_index_selects_table(lowering):
 
 
 # ---------------------------------------------------------------------------
-# The one-hot kernel (the TPU's lowering) against the twin and the gate level
+# The Pallas kernel (the TPU's lowering) against the twin and the gate level
 # ---------------------------------------------------------------------------
 
 ONEHOT_TABLES = ["design2", "design1", "dadda", "random", "bank0", "bank1",
@@ -110,18 +111,20 @@ def _onehot_table(name, signed, rng):
             lambda a, b: a * b + d[a + off, b + off])
 
 
-@pytest.mark.parametrize("M", [1, 4, 16, 64])
+@pytest.mark.parametrize("M", [1, 4, 8, 12, 16, 32, 64])
 @pytest.mark.parametrize("mode", ["asym_u8", "sym_i8"])
 @pytest.mark.parametrize("table", ONEHOT_TABLES)
 def test_onehot_kernel_bit_exact(table, mode, M):
-    """The one-hot contraction kernel (lowering 'pallas', interpret mode
+    """The qdot kernel onehot_qdot (lowering 'pallas', interpret mode
     here) gives the integer product sum_k P(qx, qw) bit for bit: equal
     to the gate-level multiplier's (or the table's own) and to the
-    blocked-XLA twin's, for any table, bank entry, mode and M, at a K
-    and an N that are no multiple of its blocks.  With real scales,
-    zero points and per-channel columns the two lowerings' outputs are
-    equal without compensation and ULP-close with it (the row table's
-    sum is a histogram dot on the kernel's side)."""
+    blocked-XLA twin's, for any table, bank entry, mode and M (both
+    weight-side contractions: the lane gather up to 12 rows a tile, the
+    one-hot above; 8, 12 and 16 around that crossover, 32 a full tile),
+    at a K and an N that are no multiple of its blocks.  With real scales, zero points and
+    per-channel columns the two lowerings' outputs are equal without
+    compensation and ULP-close with it (the row table's sum is a
+    histogram dot on the kernel's side)."""
     signed = mode == "sym_i8"
     rng = np.random.default_rng(M)
     K, N = 131, 200
@@ -164,6 +167,17 @@ def test_onehot_kernel_bit_exact(table, mode, M):
                 atol=2e-6 * max(np.abs(y["xla"]).max(), 1.0))
         else:
             np.testing.assert_array_equal(y["pallas"], y["xla"])
+
+
+@pytest.mark.parametrize("M,want", [(1, "gather"), (4, "gather"),
+                                    (12, "gather"), (13, "onehot"),
+                                    (16, "onehot"), (64, "onehot")])
+def test_contraction_follows_tile_rows(M, want):
+    """The kernel's weight-side contraction is chosen from the rows of
+    its M tile alone: the lane gather up to 12 rows, the one-hot above
+    (a tile holds at most 32 rows), whatever K and N are."""
+    for K, N in ((131, 200), (2048, 12288), (6144, 2048)):
+        assert approx_matmul.contraction(M, K, N) == want
 
 
 # ---------------------------------------------------------------------------
@@ -373,7 +387,7 @@ def test_inference_default_off_keeps_gradients():
 
 def test_platform_lowering_choice(monkeypatch):
     """Interpret mode only on the CPU; on the TPU the qdot runs the
-    one-hot kernel, decode attention the Pallas kernel, and an explicit
+    onehot_qdot kernel, decode attention the Pallas kernel, and an explicit
     request for a kernel the TPU compiler refuses raises (never
     interprets, never swaps lowerings).  Delta tables follow the qdot
     lowering."""

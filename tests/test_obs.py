@@ -94,9 +94,11 @@ def test_serve_continuous_traces_the_step_once(capsys):
 
 
 def test_serve_counts_the_qdot_lowering(capsys, monkeypatch):
-    """With the qdot steered to the TPU's lowering (the one-hot kernel,
+    """With the qdot steered to the TPU's lowering (the onehot_qdot kernel,
     in interpret mode here), every qdot call site a step trace holds
-    counts once under qdot.lowering.onehot and none under the twin's."""
+    counts once under qdot.lowering.onehot and none under the twin's,
+    and once under the kernel's contraction at its shape: the lane
+    gather, as every row count here is at most 12."""
     monkeypatch.setitem(platform._AUTO, "cpu",
                         {"qdot": platform._AUTO["tpu"]["qdot"]})
     obs.reset()
@@ -109,7 +111,11 @@ def test_serve_counts_the_qdot_lowering(capsys, monkeypatch):
     # each in the scanned layer body that a step trace traces once
     assert c[obs.QDOT_LOWERING_ONEHOT] == 4 * traces
     assert obs.QDOT_LOWERING_XLA not in c
-    assert f"qdot.lowering.onehot={4 * traces}" in capsys.readouterr().out
+    assert c[obs.QDOT_CONTRACTION_GATHER] == 4 * traces
+    assert obs.QDOT_CONTRACTION_ONEHOT not in c
+    out = capsys.readouterr().out
+    assert f"qdot.lowering.onehot={4 * traces}" in out
+    assert f"qdot.contraction.gather={4 * traces}" in out
 
 
 def test_span_list_is_bounded():

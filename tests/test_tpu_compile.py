@@ -46,8 +46,15 @@ def one_chip():
 @pytest.fixture
 def on_tpu(monkeypatch):
     """Steer kernels.platform to its TPU choices while tracing: the
-    process itself runs on the CPU backend."""
+    process itself runs on the CPU backend.  A jitted kernel's trace is
+    cached by its arguments' shapes, not by the platform it was traced
+    for, so the caches are cleared on both sides: no trace made for the
+    CPU is reused here, and none made here is reused by a later test
+    in this process."""
+    jax.clear_caches()
     monkeypatch.setattr(platform, "backend", lambda: "tpu")
+    yield
+    jax.clear_caches()
 
 
 def _spec(sharding, shape, dtype):
@@ -90,11 +97,11 @@ QDOT_SHAPES = [
 
 
 @pytest.mark.parametrize("mode", ["asym_u8", "sym_i8"])
-@pytest.mark.parametrize("M", [4, 64])               # decode, prefill rows
+@pytest.mark.parametrize("M", [4, 12, 16, 32, 64])  # decode ... prefill
 @pytest.mark.parametrize("K,N", QDOT_SHAPES)
 def test_fused_qdot_compiles(one_chip, on_tpu, K, N, M, mode):
-    """The fused serving qdot as the TPU dispatches it (the one-hot
-    contraction kernel, see kernels.platform), per-channel scales and
+    """The fused serving qdot as the TPU dispatches it (the Pallas
+    kernel onehot_qdot, see kernels.platform), per-channel scales and
     compensation tables as the calibrated serving tree carries them:
     one Pallas call, and no loop around it."""
     signed = mode == "sym_i8"
@@ -164,7 +171,7 @@ def test_serve_step_scopes_survive_tpu_fusion(one_chip, on_tpu,
         [n for n in ops if table[n] == obs.UNSCOPED][:5]
     qdots = {"qdot.wqkv", "qdot.wo", "qdot.w_gateup", "qdot.w_down"}
     kernel = [n for n, line in ops.items() if "tpu_custom_call" in line]
-    # the decode-attention kernel and the qdots' one-hot kernels
+    # the decode-attention kernel and the qdots' onehot_qdot kernels
     assert kernel and {table[n] for n in kernel} == {obs.ATTENTION} | qdots
     assert {obs.EMBED, obs.LAYERS, obs.ATTENTION, obs.FINAL_NORM,
             obs.UNEMBED, obs.SAMPLE} | qdots <= set(table.values())
